@@ -6,9 +6,11 @@ unsharded baseline row, and asserts token-for-token identity with the
 baseline on every run (the bit-exactness contract is part of the
 benchmark, not just the test suite).
 
-Run it standalone (`python -m benchmarks.bench_sharded [--smoke]`): it
-forces XLA host devices BEFORE jax loads. `benchmarks/run.py` shells
-out to it so the main bench process keeps the single real CPU device.
+Mesh rows need devices to span. On the CPU (`JAX_PLATFORMS=cpu`) this
+module gives XLA four host devices as it is imported, which must happen
+before jax loads: `benchmarks/run.py` imports it first, and the rows then
+run in the bench's own process. On an accelerator they run on the real
+devices, and a mesh larger than the device count is a SKIPPED row.
 `--smoke` is the seconds-scale CI gate wired into `make bench-smoke`.
 """
 from __future__ import annotations
@@ -16,11 +18,17 @@ from __future__ import annotations
 import os
 import sys
 
-# must precede any jax import in this process
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=4").strip()
+
+def force_cpu_host_devices(n: int = 4) -> None:
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            flags + f" --xla_force_host_platform_device_count={n}").strip()
+
+
+force_cpu_host_devices()
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))

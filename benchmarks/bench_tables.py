@@ -171,7 +171,7 @@ def fig10_incremental():
 def mask_union_micro():
     """The paper's accelerator offload: fused mask gather+union+apply."""
     import jax.numpy as jnp
-    from repro.kernels.masked_logits.kernel import masked_logits
+    from repro.kernels.fused_select.kernel import fused_select
     from repro.kernels.masked_logits.ref import masked_logits_ref
     rng = np.random.default_rng(0)
     B, V, R, A = 8, 2048, 2000, 32
@@ -188,12 +188,16 @@ def mask_union_micro():
         ref(logits, store, rows, eos)), n=20)
     emit("mask_union_jnp_ref", dt * 1e6, f"B={B};V={V};A={A}")
     cd = jnp.zeros((B, V // 32), jnp.uint32)
+    interpret = jax.default_backend() != "tpu"
     # reprolint: disable=RL003 deliberate timing bracket: this benchmark measures device wall time
     dt2 = timeit(lambda: jax.block_until_ready(
-        masked_logits(logits, store, rows, eos, cd, block_v=2048,
-                      interpret=True)), n=3)
-    emit("mask_union_pallas_interpret", dt2 * 1e6,
-         "interpret-mode (CPU correctness path; TPU is the target)")
+        fused_select(logits, store, rows, cd, eos, eos,
+                     interpret=interpret)), n=3)
+    if interpret:
+        emit("mask_union_pallas_interpret", dt2 * 1e6,
+             "interpret-mode (CPU correctness path; TPU is the target)")
+    else:
+        emit("mask_union_pallas", dt2 * 1e6, f"B={B};V={V};A={A}")
 
 
 def batched_engine_throughput(n=16, max_new=20):
@@ -364,26 +368,11 @@ def async_engine_throughput():
 def sharded_engine_throughput():
     """Tensor-parallel (vocab-sharded) engine rows: engine_sharded_m1 /
     _m2 / _m4 + an unsharded baseline (docs/sharding.md), each asserting
-    token-for-token identity with the baseline.
-
-    Runs benchmarks/bench_sharded.py in a SUBPROCESS — the main bench
-    process must keep the single real CPU device (tests/conftest.py
-    note), and the device count is fixed at backend init. The
-    subprocess forces its own XLA host devices before importing jax."""
-    import os
-    import subprocess
-    import sys
-    root = os.path.join(os.path.dirname(__file__), "..")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sharded"],
-        cwd=root, capture_output=True, text=True, timeout=1800)
-    sys.stdout.write(out.stdout)
-    # re-absorb the subprocess CSV rows into this process's artifact
-    for line in out.stdout.splitlines():
-        collect_line(line)
-    if out.returncode != 0:
-        sys.stderr.write(out.stderr)
-        raise RuntimeError("bench_sharded subprocess failed")
+    token-for-token identity with the baseline. They run in this
+    process, on its devices (benchmarks/bench_sharded.py)."""
+    from benchmarks import bench_sharded
+    if bench_sharded.main(smoke=False) != 0:
+        raise RuntimeError("bench_sharded reported identity violation")
 
 
 def assert_rows_complete(rows) -> None:

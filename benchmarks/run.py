@@ -16,6 +16,9 @@ import traceback
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+# before jax loads: CPU host devices for the mesh rows
+import benchmarks.bench_sharded  # noqa: E402,F401
+
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -27,6 +30,8 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None,
                     help="comma-separated bench-fn names to run")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     from benchmarks import bench_tables, common

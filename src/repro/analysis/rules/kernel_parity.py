@@ -46,7 +46,7 @@ def check(project):
             continue
         parts = sf.rel[len(KERNELS_PREFIX):].split("/")
         if len(parts) != 2:
-            continue         # kernels/_compat.py etc.: not a package
+            continue         # kernels/__init__.py: not a package
         pkgs.setdefault(parts[0], {})[parts[1]] = (
             sf, _uses_pallas_call(sf.tree))
     test_texts = None
@@ -69,7 +69,7 @@ def check(project):
                             f"every kernel needs a dispatch wrapper "
                             f"(ops.py) and a jnp reference (ref.py) "
                             f"to be held bit-exact against",
-                    hint="see kernels/masked_logits for the package "
+                    hint="see kernels/fused_select for the package "
                          "shape"))
         if test_texts is None:
             test_texts = [(rel, project.read_text(rel) or "")
@@ -83,5 +83,5 @@ def check(project):
                         f"tests/test_*.py — an untested Pallas kernel "
                         f"has no parity guarantee",
                 hint="add a bit-exactness fuzz vs ref.py (the "
-                     "masked_logits/paged_attention test pattern)"))
+                     "fused_select/paged_attention test pattern)"))
     return findings

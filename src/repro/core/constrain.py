@@ -483,7 +483,7 @@ class GrammarConstraint:
                 continue
             w = gc.cd_overlay(groups_list[b])
             if w is not None:
-                cd[b] = w
+                cd[b, :w.shape[0]] = w
         if first is not None:
             memo = first._cd_batch_memo
             if len(memo) >= GrammarConstraint._MEMO_CAP:

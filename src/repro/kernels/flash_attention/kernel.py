@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -110,7 +109,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, Dh), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(qh, kh, vh)

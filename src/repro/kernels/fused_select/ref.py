@@ -7,6 +7,7 @@ greedy argmax or categorical sample. The reference is the COMPOSITION
 of the legacy pieces (`masked_logits_ref` + `select_batch`), so its
 outputs are bit-identical to the pre-fusion pipeline by construction —
 the Pallas kernel is fuzzed against it (tests/test_fused_select.py).
+`sample_masked` is the sampling tail both paths run after the mask.
 
 Two sampling inputs are supported:
 
@@ -58,7 +59,16 @@ def fused_select_ref(logits, store, rows, cd, eos_allowed, constrained,
     if noise is None:
         # all-greedy host-static variant: no filter, no PRNG
         return arg, masked
-    scaled = hinted / jnp.maximum(temperature, 1e-6)[:, None]
+    return sample_masked(hinted, arg, greedy_flags, temperature, top_k,
+                         top_p, noise), masked
+
+
+def sample_masked(masked, arg, greedy_flags, temperature, top_k, top_p,
+                  noise):
+    """Sampling tail shared by the reference and the kernel op: filter
+    the temperature-scaled masked logits, argmax them plus the Gumbel
+    noise, and keep `arg` (the masked argmax) for greedy rows."""
+    scaled = masked / jnp.maximum(temperature, 1e-6)[:, None]
     scaled = topk_topp_filter(scaled, top_k, top_p)
     sampled = jnp.argmax(scaled + noise, axis=-1)
-    return jnp.where(greedy_flags, arg, sampled).astype(jnp.int32), masked
+    return jnp.where(greedy_flags, arg, sampled).astype(jnp.int32)

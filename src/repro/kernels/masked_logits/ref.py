@@ -1,4 +1,5 @@
-"""Pure-jnp oracle for the masked_logits kernel.
+"""Pure-jnp grammar-mask application: the reference half of the fused
+mask+select kernel (`kernels/fused_select`).
 
 Semantics: for each batch row b, union the packed mask-store rows
 `rows[b, :]` (int32 row ids, -1 = padding), unpack the resulting bitmask,
@@ -34,7 +35,9 @@ def masked_logits_ref(logits, store, rows, eos_allowed, eos_id: int = 1,
     words = jax.lax.reduce(gathered, jnp.uint32(0), jnp.bitwise_or,
                            dimensions=(1,))                  # [B,W]
     if cd is not None:
-        words = words | cd
+        # a residue overlay may be narrower than a lane-padded store
+        extra = words.shape[1] - cd.shape[1]
+        words = words | jnp.pad(cd, ((0, 0), (0, extra)))
     bits = (words[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)) & \
         jnp.uint32(1)
     mask = bits.reshape(B, -1)[:, :V].astype(bool)

@@ -5,18 +5,27 @@ A slot's KV lives scattered across a global page pool
 unmapped) names the pages that make up its logical sequence. The kernel
 streams the slot's pages into VMEM via scalar-prefetched BlockSpec
 index_maps (`pool` block for grid step (b, j) is page
-`page_table[b, j]` — the TPU-idiomatic dynamic gather, same scheme as
-`masked_logits`), then runs ONE exact softmax over the assembled
-`[L, K, Dh]` KV buffer on the last page step.
+`page_table[b, j]` — the TPU-idiomatic dynamic gather), then runs ONE
+exact softmax over the assembled `[K, L, Dh]` KV buffer on the last
+page step. Page validity comes from the scalar-prefetched page table.
 
 Doing the softmax once over the gathered buffer (instead of an online
-softmax per page) costs L·K·Dh·2 words of VMEM scratch — fine for
-serving-length sequences — and buys bit-exactness with the jnp
-reference and the dense decode path: the compute phase uses the
-REFERENCE'S einsum specs with only the leading batch dim peeled off
-("qkgd,skd->kgqs" / "kgqs,skd->qkgd"), which XLA lowers to the same
-per-element contractions (verified down to S = 1, where a per-head
-dot_general would take a differently-rounded gemv path).
+softmax per page) buys bit-exactness with the jnp reference and the
+dense decode path in interpret mode, and costs 2·L·K·Dh words of VMEM
+scratch.
+
+The buffers are head-major `[K, L, Dh]` and the queries `[K, G·S, Dh]`,
+so both contractions are batched matmuls with the head as the leading
+batch dim, which Mosaic puts on the MXU; the reference (`ref.attend`)
+runs the same einsums. With the head batch dim in the middle, Mosaic
+lowers them as a broadcast-multiply-reduce: at S = 32, L = 512 that is
+refused under a 48 MiB VMEM limit and takes 207 s to compile under
+120 MiB.
+
+The scratch bounds the context. At K=16, Dh=64, bf16, page 16 and the
+default scoped VMEM of a v5e, the compiled kernel fits L = 1536 and not
+L = 2048, at S = 1 and at S = 32 (tests/test_tpu_compile.py compiles
+L = 512).
 
 Grid: (B, nP) with nP innermost ("arbitrary"); q/out blocks revisit b
 across the page steps; compute fires on the last one. Two entry points
@@ -33,102 +42,93 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
 def _kernel(pt_ref,              # scalar-prefetch [B, nP] int32 page table
             pos_ref,             # scalar-prefetch [B] int32 start positions
-            q_ref,               # [1, S, H, Dh]
+            q_ref,               # [1, K, G*S, Dh]
             k_ref,               # [1, ps, K, Dh]  (page pt[b, j])
             v_ref,               # [1, ps, K, Dh]
-            o_ref,               # [1, S, H, Dh]
-            kbuf, vbuf,          # VMEM [L, K, Dh] gathered KV
-            map_ref,             # VMEM [1, L] int32 page-mapped flags
-            *, page_size: int, num_pages: int, span: int, groups: int):
+            o_ref,               # [1, K, G*S, Dh]
+            kbuf, vbuf,          # VMEM [K, L, Dh] gathered KV
+            *, page_size: int, num_pages: int, span: int):
     b = pl.program_id(0)
     j = pl.program_id(1)
     ps = page_size
+    L = num_pages * ps
 
     # ---- gather phase: copy page j into its slice of the KV buffer ----
-    kbuf[pl.ds(j * ps, ps)] = k_ref[0]
-    vbuf[pl.ds(j * ps, ps)] = v_ref[0]
-    mapped = (pt_ref[b, j] >= 0).astype(jnp.int32)
-    map_ref[0, pl.ds(j * ps, ps)] = mapped * jnp.ones((ps,), jnp.int32)
+    off = pl.multiple_of(j * ps, ps)
+    for h in range(kbuf.shape[0]):
+        kbuf[h, pl.ds(off, ps), :] = k_ref[0, :, h, :]
+        vbuf[h, pl.ds(off, ps), :] = v_ref[0, :, h, :]
 
     # ---- compute phase: one exact softmax over the whole buffer ----
     @pl.when(j == num_pages - 1)
     def _compute():
-        L = num_pages * ps
-        S, H, Dh = q_ref.shape[1:]
-        K = kbuf.shape[1]
-        G = groups
-        scale = 1.0 / (Dh ** 0.5)
-        qg = (q_ref[0] * scale).reshape(S, K, G, Dh)
-        s = jnp.einsum("qkgd,skd->kgqs", qg, kbuf[...],
-                       preferred_element_type=jnp.float32)
+        q = q_ref[0] * (1.0 / (q_ref.shape[-1] ** 0.5))
+        rows = q.shape[1]                                  # G*S, q-minor
+        lpos = jax.lax.broadcasted_iota(jnp.int32, (rows, L), 1)
         qpos = pos_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (span, L), 0)
-        lpos = jax.lax.broadcasted_iota(jnp.int32, (span, L), 1)
-        valid = (map_ref[0, :][None, :] > 0) & (lpos <= qpos)
-        s = jnp.where(valid[None, None], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("kgqs,skd->qkgd", p.astype(vbuf.dtype), vbuf[...],
+            jnp.int32, (rows, L), 0) % span
+        mapped = jnp.zeros((rows, L), jnp.bool_)
+        for jj in range(num_pages):
+            mapped |= (lpos // ps == jj) & (pt_ref[b, jj] >= 0)
+        valid = mapped & (lpos <= qpos)
+        s = jnp.einsum("kqd,ksd->kqs", q, kbuf[...],
                        preferred_element_type=jnp.float32)
-        o_ref[0] = o.reshape(S, H, Dh).astype(o_ref.dtype)
+        p = jax.nn.softmax(jnp.where(valid, s, NEG_INF), axis=-1)
+        o = jnp.einsum("kqs,ksd->kqd", p.astype(vbuf.dtype), vbuf[...],
+                       preferred_element_type=jnp.float32)
+        o_ref[0] = o.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_span(q, k_pool, v_pool, page_table, pos, *,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """q [B,S,H,Dh] (roped, unscaled); k_pool/v_pool [P,ps,K,Dh];
     page_table [B,nP] int32 (-1 = unmapped); pos [B] int32 absolute start
-    positions -> [B,S,H,Dh]. Full causal attention; GQA via the in-cell
-    group reshape (kv head = h // G)."""
+    positions -> [B,S,H,Dh]. Full causal attention; GQA via the group
+    dim (kv head = h // G)."""
     B, S, H, Dh = q.shape
     P, ps, K, _ = k_pool.shape
     nP = page_table.shape[1]
     L = nP * ps
-
-    kernel = functools.partial(_kernel, page_size=ps, num_pages=nP,
-                               span=S, groups=H // K)
+    G = H // K
+    kernel = functools.partial(_kernel, page_size=ps, num_pages=nP, span=S)
+    q = q.reshape(B, S, K, G, Dh).transpose(0, 2, 3, 1, 4).reshape(
+        B, K, G * S, Dh)
+    per_slot = lambda b, j, pt, pos: (b, 0, 0, 0)
+    page = lambda b, j, pt, pos: (jnp.maximum(pt[b, j], 0), 0, 0, 0)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, nP),
             in_specs=[
-                pl.BlockSpec((1, S, H, Dh),
-                             lambda b, j, pt, pos: (b, 0, 0, 0)),
-                pl.BlockSpec(
-                    (1, ps, K, Dh),
-                    lambda b, j, pt, pos: (
-                        jnp.maximum(pt[b, j], 0), 0, 0, 0)),
-                pl.BlockSpec(
-                    (1, ps, K, Dh),
-                    lambda b, j, pt, pos: (
-                        jnp.maximum(pt[b, j], 0), 0, 0, 0)),
+                pl.BlockSpec((1, K, G * S, Dh), per_slot),
+                pl.BlockSpec((1, ps, K, Dh), page),
+                pl.BlockSpec((1, ps, K, Dh), page),
             ],
-            out_specs=pl.BlockSpec((1, S, H, Dh),
-                                   lambda b, j, pt, pos: (b, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((L, K, Dh), k_pool.dtype),
-                pltpu.VMEM((L, K, Dh), v_pool.dtype),
-                pltpu.VMEM((1, L), jnp.int32),
-            ],
+            out_specs=pl.BlockSpec((1, K, G * S, Dh), per_slot),
+            scratch_shapes=[pltpu.VMEM((K, L, Dh), k_pool.dtype),
+                            pltpu.VMEM((K, L, Dh), v_pool.dtype)],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        name="paged_attention",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool, v_pool)
-    return out
+    )(page_table.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool,
+      v_pool)
+    return out.reshape(B, K, G, S, Dh).transpose(0, 3, 1, 2, 4).reshape(
+        B, S, H, Dh)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode(q, k_pool, v_pool, page_table, pos, *,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """Decode ([B, 1]) variant: q [B,H,Dh] -> [B,H,Dh]."""
     return paged_attention_span(q[:, None], k_pool, v_pool, page_table,
                                 pos, interpret=interpret)[:, 0]

@@ -2,10 +2,16 @@
 
 The reference reconstructs the dense cache view of a slot from its page
 table (`pool[page_table[b]]` → `[B, L, K, Dh]` with L = n_pages·ps) and
-then runs EXACTLY the einsum/softmax sequence of the dense decode path
-(`layers._self_attention_decode`) on it — same einsum specs, same mask
-constant, same dtypes — so paged attention is bit-identical to the dense
-engine's attention, and the Pallas kernel has an executable oracle.
+then runs `attend`, the attention math the dense decode path
+(`layers._self_attention_decode`) calls too, so paged attention is
+bit-identical to the dense engine's attention, and the Pallas kernel has
+an executable oracle.
+
+`attend` lays the math out head-major, as the kernel does: the queries
+of kv head k are one `[G·S, Dh]` block (group-major rows) and both
+contractions are batched matmuls with the head as a batch dim, which
+Mosaic puts on the MXU. The kernel runs the same einsums per slot, so
+in interpret mode it reproduces the reference bit for bit.
 
 Position convention: the token stored at (page_table[b, j], o) sits at
 absolute position j·ps + o of slot b's logical sequence; validity needs
@@ -22,6 +28,29 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def attend(q, kc, vc, valid):
+    """q [B,S,H,Dh] (roped, unscaled); kc/vc [B,L,K,Dh]; valid [B,S,L]
+    bool -> [B,S,H,Dh] float32. GQA: query head h reads kv head h // G.
+    bf16 operands with f32 accumulation: no f32 image of the KV cache is
+    ever materialized."""
+    B, S, H, Dh = q.shape
+    K = kc.shape[2]
+    G = H // K
+    qh = (q * (1.0 / (Dh ** 0.5))).reshape(B, S, K, G, Dh)
+    qh = qh.transpose(0, 2, 3, 1, 4).reshape(B, K, G * S, Dh)
+    # the explicit head-major transposes are the kernel's operand
+    # layouts; XLA:CPU rounds a dot by its operand layout, so without
+    # them the interpreted kernel would differ in the last bit
+    s = jnp.einsum("bkqd,bksd->bkqs", qh, kc.transpose(0, 2, 1, 3),
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(jnp.tile(valid, (1, G, 1))[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1).astype(vc.dtype)
+    o = jnp.einsum("bkqs,bksd->bkqd", p, vc.transpose(0, 2, 1, 3),
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, K, G, S, Dh).transpose(0, 3, 1, 2, 4).reshape(
+        B, S, H, Dh)
+
+
 def paged_attention_ref(q, k_pool, v_pool, page_table, pos):
     """q [B,S,H,Dh] (roped, unscaled); k_pool/v_pool [P,ps,K,Dh];
     page_table [B,nP] int32 (-1 = unmapped); pos [B] int32 absolute start
@@ -32,7 +61,6 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, pos):
     B, S, H, _ = q.shape
     nP = page_table.shape[1]
     L = nP * ps
-    G = H // K
 
     safe = jnp.maximum(page_table, 0)                        # [B, nP]
     kc = k_pool[safe].reshape(B, L, K, Dh)
@@ -43,17 +71,7 @@ def paged_attention_ref(q, k_pool, v_pool, page_table, pos):
     mapped = jnp.repeat(page_table >= 0, ps, axis=1)         # [B, L]
     valid = mapped[:, None, :] & \
         (idx[None, None, :] <= qpos[:, :, None])             # [B, S, L]
-
-    # identical math to layers._self_attention_decode (bit-exact twin)
-    scale = 1.0 / (Dh ** 0.5)
-    qg = (q * scale).reshape(B, S, K, G, -1)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kc,
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", pr.astype(vc.dtype), vc,
-                   preferred_element_type=jnp.float32)
-    return o.reshape(B, S, H, Dh).astype(q.dtype)
+    return attend(q, kc, vc, valid).astype(q.dtype)
 
 
 def paged_attention_decode_ref(q, k_pool, v_pool, page_table, pos):

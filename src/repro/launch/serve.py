@@ -41,6 +41,7 @@ from repro.core.grammars import BUILTIN, load_grammar
 from repro.core.mask_store import build_mask_store
 from repro.core.parser import IncrementalParser
 from repro.core.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serving.engine import Engine, Request
 
@@ -158,6 +159,7 @@ def main(argv=None):
                          "(adds per-step syncs — not for serving; "
                          "docs/observability.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     engine, bundles, tok = build_engine(
         args.arch, grammars=(args.grammar,),
@@ -227,6 +229,8 @@ def main(argv=None):
               f"evictions, {stats.kv_cow_copies} COW copies")
     print(f"complete: {len(complete)}/{len(states)}, "
           f"valid among complete: {valid}/{len(complete)}")
+    if valid != len(complete):
+        raise SystemExit("a completed output does not parse")
 
 
 if __name__ == "__main__":

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.core.grammars import load_grammar
 from repro.core.tokenizer import ByteTokenizer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.training.data import GrammarDataPipeline, RandomTokenPipeline
 from repro.training.optimizer import AdamWConfig
@@ -39,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -20,10 +20,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .common import (NEG_INF, apply_rope, attn_out, chunked_attention,
+from .common import (apply_rope, attn_out, chunked_attention,
                      dense_init, ffn, init_attention, init_ffn, qkv_proj,
                      rms_norm, split_keys)
 from ..kernels.paged_attention.ops import paged_attention
+from ..kernels.paged_attention.ref import attend
 from .moe import init_moe, moe_ffn
 from .rglru import (init_rglru, init_rglru_cache, rglru_decode,
                     rglru_prefill, rglru_train)
@@ -134,8 +135,7 @@ def _paged_attention_decode(p, x, cache, cfg, ctx):
     vp = vp.reshape(P * ps, K, Dh).at[flat].set(
         v.reshape(B * S, K, Dh).astype(vp.dtype),
         mode="drop").reshape(P, ps, K, Dh)
-    o = paged_attention(q, kp, vp, pt, pos,
-                        backend=ctx.get("paged_backend", "auto"))
+    o = paged_attention(q, kp, vp, pt, pos)
     return attn_out(p, o), {"k": kp, "v": vp}
 
 
@@ -194,19 +194,8 @@ def _self_attention_decode(p, x, cache, cfg, ctx):
         (kv_pos[:, None, :] <= qpos[:, :, None])              # [B, S, L]
     if w:
         valid &= kv_pos[:, None, :] > (qpos[:, :, None] - w)
-    scale = 1.0 / (q.shape[-1] ** 0.5)
-    K = kc.shape[2]
-    G = cfg.num_heads // K
-    qg = (q * scale).reshape(B, S, K, G, -1)
-    # bf16 operands + f32 accumulation: never materialize an f32 image of
-    # the KV cache (it dominated decode HBM on the 100-layer VLM)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kc,
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(valid[:, None, None, :, :], s, NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", pr.astype(vc.dtype), vc,
-                   preferred_element_type=jnp.float32)
-    o = o.reshape(B, S, cfg.num_heads, -1).astype(x.dtype)
+    # the paged path's attention math (bit-exact twins)
+    o = attend(q, kc, vc, valid).astype(x.dtype)
     return attn_out(p, o), {"k": kc, "v": vc, "kv_pos": kv_pos}
 
 

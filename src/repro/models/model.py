@@ -32,18 +32,6 @@ LB_COEF = 0.01
 Z_COEF = 0.001
 
 
-@jax.custom_jvp
-def _opt_barrier(x):
-    return jax.lax.optimization_barrier(x)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    # identity JVP: older jax has no differentiation rule for
-    # optimization_barrier; the barrier only matters for primal scheduling
-    return _opt_barrier(primals[0]), tangents[0]
-
-
 def layer_groups(cfg: ModelConfig):
     """-> list of (pattern tuple, count). Decoder-side stack."""
     L = cfg.num_layers
@@ -159,7 +147,7 @@ class Model:
                 # convert of x out of the backward while-loop, material-
                 # izing an f32 copy of the whole [L,B,S,D] residual stack
                 # (observed 12.9 GB/device on internlm2 train_4k).
-                x = _opt_barrier(x)
+                x = jax.lax.optimization_barrier(x)
                 a = {"lb": jnp.zeros((), jnp.float32),
                      "z": jnp.zeros((), jnp.float32)}
                 for j, kind in enumerate(pat):
@@ -361,9 +349,11 @@ class Model:
 
         caches = []
         for pat, count in layer_groups(cfg):
+            # broadcast, not zeros: an empty line's kv_pos is -1
             group = tuple(
-                jax.tree.map(lambda a: jnp.zeros((count,) + a.shape, a.dtype),
-                             one(kind))
+                jax.tree.map(
+                    lambda a: jnp.broadcast_to(a, (count,) + a.shape),
+                    one(kind))
                 for kind in pat)
             caches.append(group)
         return caches

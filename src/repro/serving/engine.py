@@ -63,9 +63,8 @@ from repro.distributed.sharding import (serving_cache_shardings,
                                         serving_store_sharding)
 from repro.core.constrain import accept_width
 from repro.kernels.fused_select.ops import (fused_mask_select,
+                                            fused_mask_select_span,
                                             gumbel_noise)
-from repro.kernels.masked_logits.ops import (apply_grammar_mask,
-                                             apply_grammar_mask_span)
 from repro.obs import Telemetry
 from repro.serving.kvpool import PagedAllocator, PoolExhausted
 from repro.spec.scheduler import SPAN_BUCKETS, SlotPhase, SpecConfig
@@ -214,10 +213,10 @@ class _SelectCtx:
 class Engine:
     def __init__(self, model, params, tokenizer: ByteTokenizer,
                  grammar_bundles: dict, max_len: int = 512,
-                 opportunistic: bool = False, mask_backend: str = "jnp",
+                 opportunistic: bool = False,
                  slots: int = 4, paged: bool = False, page_size: int = 16,
                  num_pages: Optional[int] = None, prefill_chunk: int = 32,
-                 attn_backend: str = "auto", mesh=None,
+                 mesh=None,
                  trunk_shard: bool = False, overlap: bool = True,
                  grammar_mode: str = "grammar_mask",
                  telemetry: bool = True, devtime: bool = False):
@@ -268,14 +267,12 @@ class Engine:
         self.grammar_mode = grammar_mode
         self.max_len = max_len
         self.opportunistic = opportunistic
-        self.mask_backend = mask_backend
         self.slots = max(1, int(slots))
         self.paged = bool(paged)
         self.page_size = max(1, int(page_size))
         self.max_pages = -(-max_len // self.page_size)
         self.num_pages = int(num_pages or self.slots * self.max_pages)
         self.prefill_chunk = max(1, int(prefill_chunk))
-        self.attn_backend = attn_backend
         self.mesh = mesh
         self.trunk_shard = bool(trunk_shard)
         self.overlap = bool(overlap)
@@ -330,15 +327,21 @@ class Engine:
         words = (self.tok.vocab_size + 31) // 32
         cat = (np.concatenate(parts, axis=0) if parts
                else np.zeros((1, words), np.uint32))
-        if self.mesh is not None:
+        if self.mesh is None:
+            # zero rows/words up to the fused kernel's (8, 128) HBM tile,
+            # so its row DMAs are aligned and no step pads the store
+            # again. A mesh runs the reference, and there the word
+            # shards must stay aligned with the logits' vocab shards.
+            cat = np.pad(cat, ((0, -cat.shape[0] % 8),
+                               (0, -cat.shape[1] % 128)))
+            self._store_cat = jnp.asarray(cat)
+        else:
             # the packed mask store lives vocab-sharded on the mesh:
             # word w of every row sits on the shard owning vocab ids
             # [w*32, (w+1)*32) — the row gather + bitwise union +
-            # logits mask in kernels/masked_logits stay shard-local
+            # logits mask of the reference stay shard-local
             self._store_cat = jax.device_put(
                 cat, serving_store_sharding(self.mesh, cat.shape[1]))
-        else:
-            self._store_cat = jnp.asarray(cat)
 
     def register_grammar(self, name: str, bundle) -> None:
         """Hot-register a freshly compiled (grammar, table, store) bundle.
@@ -406,19 +409,14 @@ class Engine:
                                             trunk_shard=self.trunk_shard))
 
     def _build_batched_fns(self):
-        backend = self.mask_backend
         vocab = self.model.cfg.vocab_size
 
         def fused_greedy(logits, store, rows, cd, eos, constrained):
             """Host-static all-greedy variant: one fused mask+argmax
             device call — no filter math, no PRNG (the selected ids are
             the masked argmax regardless of the per-slot configs)."""
-            B = logits.shape[0]
-            ids, masked = fused_mask_select(
-                logits, store, rows, cd, eos, constrained,
-                jnp.ones((B,), bool), jnp.ones((B,), jnp.float32),
-                jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32),
-                backend=backend)
+            ids, masked = fused_mask_select(logits, store, rows, cd, eos,
+                                            constrained)
             ok = jnp.any(masked > NEG_INF / 2, axis=-1)
             return masked, ids, ok
 
@@ -430,7 +428,7 @@ class Engine:
             the PRNG work rides the previous step's resolve."""
             ids, masked = fused_mask_select(
                 logits, store, rows, cd, eos, constrained,
-                greedy, temp, top_k, top_p, noise=noise, backend=backend)
+                greedy, temp, top_k, top_p, noise=noise)
             ok = jnp.any(masked > NEG_INF / 2, axis=-1)
             return masked, ids, ok
 
@@ -455,10 +453,8 @@ class Engine:
             the precomputed store rows + per-position residue overlay,
             padding/unconstrained pass through). The accept test is a
             host-side == against the [B, S] ids."""
-            masked = apply_grammar_mask_span(logits, store, rows, eos,
-                                             backend=backend,
-                                             constrained=constrained,
-                                             cd=cd)
+            _, masked = fused_mask_select_span(logits, store, rows, cd, eos,
+                                               constrained)
             ids = select_span(masked, keys, greedy, temp, top_k, top_p)
             ok = jnp.any(masked > NEG_INF / 2, axis=-1)
             return masked, ids, ok
@@ -471,8 +467,7 @@ class Engine:
             the same [B, V] it would from a dense decode_step."""
             logits, c = self.model.decode_span(
                 p, c, toks, pos, feed_mask=fm,
-                batch_ctx={"page_table": pt,
-                           "paged_backend": self.attn_backend})
+                batch_ctx={"page_table": pt})
             B, S = toks.shape
             sel_logits = logits[jnp.arange(B), jnp.clip(sel, 0, S - 1)]
             return sel_logits, c
@@ -499,8 +494,7 @@ class Engine:
         self._span_decode_paged = self._shard_jit(
             lambda p, c, toks, pos, fm, pt: self.model.decode_span(
                 p, c, toks, pos, feed_mask=fm,
-                batch_ctx={"page_table": pt,
-                           "paged_backend": self.attn_backend}))
+                batch_ctx={"page_table": pt}))
         self._span_feed_paged = self._shard_jit(span_feed_paged)
         self._copy_page = self._shard_jit(copy_page)
 
@@ -1150,10 +1144,8 @@ class Engine:
             cdw = gc.cd_overlay(sg.groups)
             cd = None if cdw is None else cdw[None, :]
         with obs.span("mask_dispatch") as sp_disp:
-            masked = apply_grammar_mask(logits, self._store_cat,
-                                        rows, eos,
-                                        backend=self.mask_backend,
-                                        cd=cd)
+            _, masked = fused_mask_select(logits, self._store_cat, rows,
+                                          cd, eos, np.ones(1, bool))
         st.mask_time += sp_rows.dur + sp_cd.dur + sp_disp.dur
         st.mask_computations += 1
 

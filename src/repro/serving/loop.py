@@ -981,7 +981,7 @@ class SpecMode(_ModeBase):
             cdm = np.zeros((B, S, W), np.uint32)
             for (b, f), (sm, _) in span_sms.items():
                 if sm.cd_words is not None:
-                    cdm[b, f] = sm.cd_words
+                    cdm[b, f, :sm.cd_words.shape[0]] = sm.cd_words
         with loop.tele.device_span("mask_sample") as dv:
             with loop.tele.span("mask_dispatch"):
                 salts = np.array([slot_state[b].steps if slot_state[b]

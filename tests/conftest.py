@@ -1,6 +1,7 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS device-count override here — smoke
-tests and benches must see the single real CPU device; only
-launch/dryrun.py (run as its own process) forces 512 host devices."""
+"""Shared fixtures. NOTE: no XLA_FLAGS device-count override here — the
+tests must see the single real CPU device. launch/dryrun.py forces 512
+host devices and benchmarks/run.py 4 (for the mesh rows), each in its
+own process."""
 import os
 import sys
 

@@ -10,12 +10,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 
 def _fake_mesh():
     # single-device "mesh" with the production axis names for rule tests
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 class _MeshShape:
@@ -88,11 +89,12 @@ from repro.models.model import build_model
 from repro.distributed.api import use_sharding
 from repro.distributed.sharding import (activation_rules, batch_shardings,
                                         cache_shardings, params_shardings)
+from repro.launch.mesh import make_local_mesh
 from repro.launch.shapes import batch_specs
 
 cfg = get_config(__ARCH__).reduced(vocab_size=512, d_model=256)
 model = build_model(cfg)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_local_mesh(4)
 params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
 p_sh = params_shardings(params, mesh)
 rules = activation_rules(mesh, cfg, 4)

@@ -15,6 +15,7 @@ except ImportError:  # degrade gracefully: only @given tests skip
 
 from repro.core.decoding import select_batch
 from repro.kernels.fused_select.kernel import fused_select
+from repro.kernels.fused_select.ops import fused_mask_select
 from repro.kernels.fused_select.ref import fused_select_ref, gumbel_noise
 from repro.kernels.masked_logits.ref import masked_logits_ref
 
@@ -63,10 +64,10 @@ def test_all_variants_bit_identical(B, V, R, A):
     ids_rn, masked_rn = fused_select_ref(logits, store, rows, cd, eos,
                                          cons, greedy, temp, top_k, top_p,
                                          noise=noise)
-    # Pallas kernel, noise variant (interpret=True executes on CPU)
-    ids_k, masked_k = fused_select(logits, store, rows, cd, eos, cons,
-                                   greedy, temp, top_k, top_p, noise,
-                                   mode="sample", interpret=True)
+    # Pallas kernel + sampling tail, noise variant (interpret on CPU)
+    ids_k, masked_k = fused_mask_select(logits, store, rows, cd, eos, cons,
+                                        greedy, temp, top_k, top_p,
+                                        noise=noise, backend="pallas")
     np.testing.assert_array_equal(np.asarray(ids_legacy),
                                   np.asarray(ids_rk))
     np.testing.assert_array_equal(np.asarray(ids_rk), np.asarray(ids_rn))
@@ -83,11 +84,8 @@ def test_greedy_variant_matches_argmax():
     (logits, store, rows, cd, eos, cons, _, temp, top_k, top_p,
      keys) = _step_inputs(np.random.default_rng(3), 4, 1024, 80, 8)
     ones = jnp.ones((4,), bool)
-    ids_g, masked_g = fused_select(
-        logits, store, rows, cd, eos, cons, ones,
-        jnp.ones((4,), jnp.float32), jnp.zeros((4,), jnp.int32),
-        jnp.ones((4,), jnp.float32), jnp.zeros(logits.shape, jnp.float32),
-        mode="greedy", interpret=True)
+    ids_g, masked_g = fused_select(logits, store, rows, cd, eos, cons,
+                                   interpret=True)
     ref = masked_logits_ref(logits, store, rows, eos, constrained=cons,
                             cd=cd)
     np.testing.assert_array_equal(np.asarray(ids_g),
@@ -113,20 +111,20 @@ def test_none_cd_means_no_overlay():
 
 
 def test_span_form_matches_batch():
-    from repro.kernels.fused_select.ops import (fused_mask_select,
-                                                fused_mask_select_span)
+    from repro.kernels.fused_select.ops import fused_mask_select_span
     B, S, V, R, A = 2, 3, 512, 40, 6
     (logits, store, rows, cd, eos, cons, greedy, temp, top_k, top_p,
      keys) = _step_inputs(np.random.default_rng(17), B * S, V, R, A)
+    noise = gumbel_noise(keys, V)
     ids_flat, masked_flat = fused_mask_select(
         logits, store, rows, cd, eos, cons,
         jnp.repeat(greedy[:B], S), jnp.repeat(temp[:B], S),
-        jnp.repeat(top_k[:B], S), jnp.repeat(top_p[:B], S), keys=keys)
+        jnp.repeat(top_k[:B], S), jnp.repeat(top_p[:B], S), noise=noise)
     ids_span, masked_span = fused_mask_select_span(
         logits.reshape(B, S, V), store, rows.reshape(B, S, A),
         cd.reshape(B, S, -1), eos.reshape(B, S), cons.reshape(B, S),
         greedy[:B], temp[:B], top_k[:B], top_p[:B],
-        keys=keys.reshape(B, S, 2))
+        noise=noise.reshape(B, S, V))
     np.testing.assert_array_equal(np.asarray(ids_flat).reshape(B, S),
                                   np.asarray(ids_span))
     np.testing.assert_array_equal(
@@ -147,10 +145,10 @@ def test_fused_select_property(B, A, seed):
     ids_ref, masked_ref = fused_select_ref(logits, store, rows, cd, eos,
                                            cons, greedy, temp, top_k,
                                            top_p, keys=keys)
-    ids_k, masked_k = fused_select(logits, store, rows, cd, eos, cons,
-                                   greedy, temp, top_k, top_p,
-                                   gumbel_noise(keys, V),
-                                   mode="sample", interpret=True)
+    ids_k, masked_k = fused_mask_select(logits, store, rows, cd, eos, cons,
+                                        greedy, temp, top_k, top_p,
+                                        noise=gumbel_noise(keys, V),
+                                        backend="pallas")
     np.testing.assert_array_equal(np.asarray(ids_ref), np.asarray(ids_k))
     np.testing.assert_array_equal(np.asarray(masked_ref),
                                   np.asarray(masked_k))

@@ -11,20 +11,31 @@ except ImportError:  # degrade gracefully: only @given tests skip
 
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.masked_logits.kernel import masked_logits
+from repro.kernels.fused_select.kernel import fused_select
 from repro.kernels.masked_logits.ref import masked_logits_ref
+
+
+def masked_logits(logits, store, rows, eos, cd):
+    """The fused kernel's masked-logits output (every row constrained),
+    in interpret mode."""
+    ones = jnp.ones(eos.shape, bool)
+    return fused_select(logits, store, rows, cd, eos, ones,
+                        interpret=True)[1]
 
 
 # ------------------------------ masked_logits ------------------------------
 
-@pytest.mark.parametrize("B,V,R,A,block_v", [
-    (1, 512, 32, 4, 512),
-    (4, 2048, 300, 12, 512),
-    (3, 1024, 64, 48, 1024),
-    (2, 4096, 128, 8, 2048),
+# V = 4480 and 9 rows leave a partial last vocab chunk and a partial
+# last batch block, the shapes of deployment vocabularies (V = 151936)
+@pytest.mark.parametrize("B,V,R,A", [
+    (1, 512, 32, 4),
+    (4, 2048, 300, 12),
+    (3, 1024, 64, 48),
+    (2, 4096, 128, 8),
+    (9, 4480, 128, 8),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_masked_logits_matches_ref(B, V, R, A, block_v, dtype):
+def test_masked_logits_matches_ref(B, V, R, A, dtype):
     rng = np.random.default_rng(B * V + A)
     store = rng.integers(0, 2 ** 32, size=(R, V // 32), dtype=np.uint32)
     rows = rng.integers(-1, R, size=(B, A)).astype(np.int32)
@@ -34,8 +45,7 @@ def test_masked_logits_matches_ref(B, V, R, A, block_v, dtype):
     args = (jnp.asarray(logits, dtype), jnp.asarray(store),
             jnp.asarray(rows), jnp.asarray(eos))
     ref = masked_logits_ref(*args, cd=jnp.asarray(cd))
-    out = masked_logits(*args, jnp.asarray(cd), block_v=block_v,
-                        interpret=True)
+    out = masked_logits(*args, jnp.asarray(cd))
     np.testing.assert_array_equal(np.asarray(ref, np.float32),
                                   np.asarray(out, np.float32))
 
@@ -56,8 +66,7 @@ def test_masked_logits_property(B, A, seed):
     cd = rng.integers(0, 2 ** 32, size=(B, V // 32), dtype=np.uint32)
     args = (jnp.asarray(logits), jnp.asarray(store), jnp.asarray(rows),
             jnp.asarray(eos))
-    out = np.asarray(masked_logits(*args, jnp.asarray(cd), block_v=256,
-                                   interpret=True))
+    out = np.asarray(masked_logits(*args, jnp.asarray(cd)))
     ref = np.asarray(masked_logits_ref(*args, cd=jnp.asarray(cd)))
     np.testing.assert_array_equal(out, ref)
     # property: every unmasked position was allowed by some row, the

@@ -100,6 +100,16 @@ def test_decode_matches_train_forward(arch):
             rtol=tol, atol=tol, err_msg=f"{arch} step {i}")
 
 
+def test_init_decode_caches_marks_lines_empty():
+    """Fresh decode caches hold no position: every kv_pos is -1, so a
+    span fed into them (the engine's dense prefill) attends only to what
+    it wrote."""
+    model = build_model(get_config("qwen1.5-0.5b").reduced())
+    caches = model.init_decode_caches(2, 16)
+    kv_pos = [np.asarray(c["kv_pos"]) for group in caches for c in group]
+    assert kv_pos and all((k == -1).all() for k in kv_pos)
+
+
 def test_sliding_window_decode_ring_buffer():
     cfg = get_config("internlm2-1.8b").reduced(sliding_window=8)
     model = build_model(cfg)

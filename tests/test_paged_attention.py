@@ -14,7 +14,7 @@ except ImportError:  # degrade gracefully: only @given tests skip
 from repro.kernels.paged_attention.kernel import (paged_attention_decode,
                                                   paged_attention_span)
 from repro.kernels.paged_attention.ops import paged_attention
-from repro.kernels.paged_attention.ref import paged_attention_ref
+from repro.kernels.paged_attention.ref import attend, paged_attention_ref
 
 NEG_INF = -1e30
 
@@ -41,8 +41,9 @@ def _setup(rng, B, S, H, K, Dh, ps, nP, P, dtype=np.float32, min_pos=None):
 
 
 def _dense_twin(q, kp, vp, pt, pos):
-    """The dense decode path's exact math (layers._self_attention_decode)
-    on a densely materialized copy of the paged cache."""
+    """The dense decode path (layers._self_attention_decode): kv_pos
+    validity and its attention math (`ref.attend`) on a densely
+    materialized copy of the paged cache."""
     P, ps, K, Dh = kp.shape
     B, S, H, _ = q.shape
     nP = pt.shape[1]
@@ -61,16 +62,8 @@ def _dense_twin(q, kp, vp, pt, pos):
     qpos = np.asarray(pos)[:, None] + np.arange(S)[None, :]
     valid = (kv_pos[:, None, :] >= 0) & \
         (kv_pos[:, None, :] <= qpos[:, :, None])
-    scale = 1.0 / (Dh ** 0.5)
-    G = H // K
-    qg = (q * scale).reshape(B, S, K, G, Dh)
-    s = jnp.einsum("bqkgd,bskd->bkgqs", qg, jnp.asarray(kc),
-                   preferred_element_type=jnp.float32)
-    s = jnp.where(jnp.asarray(valid)[:, None, None, :, :], s, NEG_INF)
-    pr = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", pr.astype(vc.dtype),
-                   jnp.asarray(vc), preferred_element_type=jnp.float32)
-    return o.reshape(B, S, H, Dh).astype(q.dtype)
+    return attend(q, jnp.asarray(kc), jnp.asarray(vc),
+                  jnp.asarray(valid)).astype(q.dtype)
 
 
 def _bits(x):
@@ -106,6 +99,20 @@ def test_bfloat16_bit_exact():
     dense = _dense_twin(q, kp, vp, pt, pos)
     np.testing.assert_array_equal(_bits(ref), _bits(ker))
     np.testing.assert_array_equal(_bits(ref), _bits(dense))
+
+
+@pytest.mark.parametrize("B,S,H,K", [(3, 4, 8, 4), (4, 1, 4, 2),
+                                     (2, 5, 6, 3), (2, 1, 16, 16)])
+def test_head_major_layout_matches_ref(B, S, H, K):
+    """The kernel's head-major layout (batched matmuls over the head
+    dim), the one the chip compiles, run through the interpreter across
+    GQA groupings, MHA at S = 1 included: bit-exact with the reference."""
+    rng = np.random.default_rng(B * S + H)
+    args = _setup(rng, B=B, S=S, H=H, K=K, Dh=16, ps=4, nP=5,
+                  P=B * 5 + 2)
+    ref = paged_attention_ref(*args)
+    ker = paged_attention_span(*args, interpret=True)
+    np.testing.assert_array_equal(_bits(ker), _bits(ref))
 
 
 def test_unmapped_pages_never_contribute():

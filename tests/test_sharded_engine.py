@@ -97,6 +97,18 @@ def test_mesh1_generate_identical(harness):
         assert stats.mesh_devices == 1
 
 
+def test_mesh1_store_words_follow_vocab(harness):
+    """Under a mesh the packed store keeps ceil(V/32) words, so its word
+    shards line up with the logits' vocab shards; only the unsharded
+    engine pads it to the fused kernel's (8, 128) tile."""
+    make, _ = harness
+    base, m1 = make(), make(1)
+    V = base.model.cfg.vocab_size
+    assert m1._store_cat.shape[1] == -(-V // 32)
+    assert base._store_cat.shape[0] % 8 == 0
+    assert base._store_cat.shape[1] % 128 == 0
+
+
 def test_mesh_requires_model_axis(harness):
     make, bundles = harness
     eng = make()

@@ -355,7 +355,7 @@ def test_decode_span_feed_mask_gates_cache_writes(tokenizer):
 
 def test_masked_logits_span_kernel_matches_ref():
     import jax.numpy as jnp
-    from repro.kernels.masked_logits.kernel import masked_logits_span
+    from repro.kernels.fused_select.ops import fused_mask_select_span
     from repro.kernels.masked_logits.ref import masked_logits_span_ref
     rng = np.random.default_rng(0)
     B, K, V, R, A = 3, 4, 256, 64, 6
@@ -366,15 +366,16 @@ def test_masked_logits_span_kernel_matches_ref():
     eos = jnp.asarray(rng.integers(0, 2, (B, K)).astype(bool))
     cd = jnp.asarray(rng.integers(0, 2 ** 32, (B, K, V // 32),
                                   dtype=np.uint32))
-    out = masked_logits_span(logits, store, rows, eos, cd, block_v=128,
-                             interpret=True)
+    _, out = fused_mask_select_span(logits, store, rows, cd, eos,
+                                    jnp.ones((B, K), bool),
+                                    backend="pallas")
     ref = masked_logits_span_ref(logits, store, rows, eos, cd=cd)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
 def test_apply_grammar_mask_span_constrained_passthrough():
     import jax.numpy as jnp
-    from repro.kernels.masked_logits.ops import apply_grammar_mask_span
+    from repro.kernels.fused_select.ops import fused_mask_select_span
     rng = np.random.default_rng(1)
     B, K, V, R, A = 2, 3, 128, 16, 4
     store = jnp.asarray(rng.integers(0, 2 ** 32, (R, V // 32),
@@ -384,8 +385,8 @@ def test_apply_grammar_mask_span_constrained_passthrough():
     eos = jnp.asarray(np.zeros((B, K), bool))
     cons = jnp.asarray(np.array([[True, False, True],
                                  [False, False, True]]))
-    out = apply_grammar_mask_span(logits, store, rows, eos, backend="jnp",
-                                  constrained=cons)
+    _, out = fused_mask_select_span(logits, store, rows, None, eos, cons,
+                                    backend="jnp")
     out = np.asarray(out)
     np.testing.assert_array_equal(out[0, 1], np.asarray(logits)[0, 1])
     np.testing.assert_array_equal(out[1, 0], np.asarray(logits)[1, 0])
