@@ -3,7 +3,7 @@
 
 devbridge is the single sanctioned module binding the device sync into
 the observability layer as an injected capability (invoked only in
-bench/profile mode; tests/test_devtime.py proves serving never calls
+bench mode; tests/test_devtime.py proves serving never calls
 it). Any other identifier-level use of `block_until_ready` anywhere in
 the scanned tree is a finding — a sync smuggled into serving would
 serialize the XGrammar-style host/device overlap, and one hidden in a

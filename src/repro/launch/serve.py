@@ -154,7 +154,7 @@ def main(argv=None):
                          "docs/observability.md) — count stats stay "
                          "exact, timing stats read 0")
     ap.add_argument("--devtime", action="store_true",
-                    help="bench/profile mode: device-span brackets sync "
+                    help="bench mode: device-span brackets sync "
                          "on exit so stats carry true device intervals "
                          "(adds per-step syncs — not for serving; "
                          "docs/observability.md)")

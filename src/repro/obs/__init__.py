@@ -9,7 +9,7 @@ from .lifecycle import LifecycleTracker, NullLifecycle
 from .trace import Tracer, DEFAULT_CAPACITY
 from .buildinfo import build_info, git_revision, run_meta_str
 from .devtime import (DEVICE_TRACK_PREFIX, DeviceTimer, NULL_DEV_SPAN,
-                      ProfilerSession)
+                      PROFILE_MARK, ProfilerSession)
 from .telemetry import (ATTR_FORWARD_PHASES, ATTR_HOST_GRAMMAR_PHASES,
                         ATTR_MASK_PHASES, DISABLED_SPAN_BUDGET_S,
                         ENABLED_SPAN_BUDGET_S, NULL_SPAN, Telemetry)
@@ -21,7 +21,7 @@ __all__ = [
     "Tracer", "DEFAULT_CAPACITY",
     "build_info", "git_revision", "run_meta_str",
     "DeviceTimer", "ProfilerSession", "NULL_DEV_SPAN",
-    "DEVICE_TRACK_PREFIX",
+    "DEVICE_TRACK_PREFIX", "PROFILE_MARK",
     "Telemetry", "NULL_SPAN",
     "ATTR_HOST_GRAMMAR_PHASES", "ATTR_MASK_PHASES",
     "ATTR_FORWARD_PHASES",

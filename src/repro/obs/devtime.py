@@ -11,10 +11,11 @@ the no-sync contract:
   * **serving mode (default)** — `span()` returns the shared no-op
     `NULL_DEV_SPAN`; nothing syncs, the PR 7 contract holds verbatim
     (tests/test_devtime.py proves the injected sync fn is never called).
-  * **bench / profile mode** — `span(fn)` brackets the jitted call and
-    `done(out)` hands the dispatched arrays to the *injected* `sync_fn`
-    (`jax.block_until_ready`, bound by serving/devbridge.py — this
-    package still never imports jax). The bracket then covers dispatch
+  * **bench mode** (`Engine(devtime=True)`) — `span(fn)` brackets the
+    jitted call and `done(out)` hands the dispatched arrays to the
+    *injected* `sync_fn` (`jax.block_until_ready`, bound by
+    serving/devbridge.py — this package still never imports jax). The
+    bracket then covers dispatch
     **plus device execution**, i.e. a true device interval on the host
     `perf_counter` clock, so device tracks align with host spans in one
     Perfetto timeline with no clock translation.
@@ -30,13 +31,18 @@ Each measured interval feeds three surfaces:
     parsed from the compiled HLO), achieved FLOP/s and bytes/s for
     roofline positioning (benchmarks/roofline.position).
 
-`ProfilerSession` is the `POST /profile start|stop|dump` state machine:
-start flips the owning DeviceTimer into sync-on-exit mode, starts trace
-capture, and (when devbridge bound one) starts a `jax.profiler` trace
-into a temp dir; dump merges the profiler's own device-thread events
-into the exported Chrome timeline (`collect_chrome_events`, parsed from
-`*.trace.json.gz` with stdlib gzip+json and linearly rebased onto the
-host clock window of the capture).
+`ProfilerSession` is the `POST /profile start|stop|dump` state machine
+and holds the profiler capabilities devbridge injects. start starts
+trace capture and (when devbridge bound one) a `jax.profiler` trace
+into a temp dir, and writes a clock mark: a profiler host annotation
+whose `perf_counter` time it keeps. It leaves the DeviceTimer alone —
+synced brackets would serialize the very overlap the capture is meant
+to show. dump merges the profiler's own device-thread events into the
+exported Chrome timeline (`collect_chrome_events`, parsed from
+`*.trace.json.gz` with stdlib gzip+json), mapped onto the host clock
+through the mark. While the tracer is active, every Telemetry span also
+opens a profiler annotation (`open`) and each step a step annotation
+(`open_step`), so the profiler's trace carries the program's phases.
 
 Pure stdlib — no jax/numpy anywhere in repro.obs; every device-touching
 capability is injected by the caller that already owns jax.
@@ -56,6 +62,10 @@ from .registry import MetricsRegistry, PHASE_BUCKETS
 # Track-name prefix for device intervals in the exported trace: host
 # phases keep their PR 7 tracks, device intervals land beside them.
 DEVICE_TRACK_PREFIX = "device:"
+# The profiler annotation that ties the profiler's clock to perf_counter
+# (ProfilerSession.start), and the name of each step's step annotation.
+PROFILE_MARK = "repro_clock_mark"
+STEP_ANNOTATION = "decode"
 
 
 class _NullDevSpan:
@@ -110,7 +120,7 @@ class DeviceTimer:
     """Mode-gated device-interval measurement over injected sync.
 
     `enabled` is False in serving (span() is free and never syncs) and
-    True in bench/profile mode. `sync_fn` is injected exactly once by
+    True in bench mode. `sync_fn` is injected exactly once by
     serving/devbridge.py; until it is bound, span() no-ops even when
     enabled, so obs stays import-pure and unbound timers are harmless.
     """
@@ -216,50 +226,77 @@ class DeviceTimer:
 
 # --------------------------- profiler session ---------------------------
 
-# Chrome-trace thread names that carry real device/kernel execution in a
-# jax.profiler capture (TFRT CPU client executor threads, TPU/GPU device
-# streams). Python host-callstack threads are dropped from the merge —
+# Chrome-trace thread (or process) names that carry real device/kernel
+# execution in a jax.profiler capture (the CPU client's executor
+# threads, TPU/GPU device planes and streams). Python host-callstack
+# threads and the CPU compiler's threads are dropped from the merge —
 # the host side of the merged view comes from our own phase spans.
-_DEVICE_THREAD_MARKERS = ("XLATfrtCpuClient", "/device:", "TPU", "GPU",
-                          "Stream", "xla-cpu")
+_DEVICE_THREAD_MARKERS = ("CpuClient", "/device:", "TPU", "GPU",
+                          "Stream")
 # Executor bookkeeping slices that would drown the kernels they schedule
-_NOISE_EVENTS = ("ThreadpoolListener", "ThunkExecutor")
+_NOISE_EVENTS = ("ThreadpoolListener", "ThunkExecutor",
+                 "SlinkyThreadPool", "end: ")
 
 
 class ProfilerSession:
-    """State machine behind `POST /profile start|stop|dump`.
+    """State machine behind `POST /profile start|stop|dump`, and the
+    profiler capabilities devbridge injects (start/stop a capture, host
+    annotations).
 
-    start():  remember the DeviceTimer's mode, flip it to sync-on-exit,
-              start trace capture, and start the backend profiler (when
-              devbridge bound one) into a fresh temp dir.
-    stop():   stop the backend profiler, restore the DeviceTimer mode.
+    start():  start trace capture and the backend profiler (when
+              devbridge bound one) into a fresh temp dir, then write the
+              clock mark: an annotation named `PROFILE_MARK`, with its
+              perf_counter time kept as `mark_t`.
+    stop():   stop the backend profiler and trace capture.
     dump():   chrome events collected from the backend profiler's
-              `*.trace.json.gz`, rebased onto the host-clock window of
-              the capture — merged by Tracer.export_chrome(extra=...).
+              `*.trace.json.gz`, each device slice placed at
+              `mark_t + (ts - ts of the mark)` on the host clock —
+              merged by Tracer.export_chrome(extra=...).
 
-    The host perf_counter timestamps taken at start/stop are the
-    alignment anchors: profiler event timestamps are offsets on the
-    profiler's own clock, so the earliest captured event is pinned to
-    the session's host start time. Visual alignment, not ns-exact —
-    the authoritative device intervals are the DeviceTimer spans, which
-    are measured on the host clock directly.
+    One clock: the mark ties the profiler's clock to perf_counter, so a
+    device slice lands at the host time it ran, however long after
+    start() the first device op came.
     """
 
-    def __init__(self, devtimer: DeviceTimer, tracer):
-        self.devtimer = devtimer
+    def __init__(self, tracer):
         self.tracer = tracer
         self.profiler_start: Optional[Callable] = None  # (log_dir) -> None
         self.profiler_stop: Optional[Callable] = None   # () -> None
+        self.annotate: Optional[Callable] = None        # (name) -> ctx
+        self.step_annotate: Optional[Callable] = None   # (name, step_num=)
         self.active = False
         self.log_dir: Optional[str] = None
         self.host_t0 = 0.0
         self.host_t1 = 0.0
-        self._was_enabled = False
+        self.mark_t: Optional[float] = None
 
-    def bind(self, profiler_start: Callable, profiler_stop: Callable):
+    def bind(self, profiler_start: Callable, profiler_stop: Callable,
+             annotate: Optional[Callable] = None,
+             step_annotate: Optional[Callable] = None):
         if self.profiler_start is None:
             self.profiler_start = profiler_start
             self.profiler_stop = profiler_stop
+            self.annotate = annotate
+            self.step_annotate = step_annotate
+
+    # ---------------------------- annotations --------------------------
+
+    def open(self, name: str):
+        """An entered profiler host annotation, or None where none is
+        bound. The caller exits it."""
+        if self.annotate is None:
+            return None
+        ann = self.annotate(name)
+        ann.__enter__()
+        return ann
+
+    def open_step(self, n: int):
+        """An entered profiler step annotation for step n, or None."""
+        if self.step_annotate is None:
+            return None
+        ann = self.step_annotate(STEP_ANNOTATION, step_num=n)
+        ann.__enter__()
+        return ann
 
     # ------------------------------ control ----------------------------
 
@@ -269,9 +306,7 @@ class ProfilerSession:
         self.log_dir = log_dir or tempfile.mkdtemp(prefix="repro_profile_")
         self.host_t0 = time.perf_counter()
         self.host_t1 = 0.0
-        self._was_enabled = self.devtimer.enabled
-        self.devtimer.enabled = True        # sync-on-exit device spans:
-        # the documented profile-mode exception to the no-sync contract
+        self.mark_t = None
         self.tracer.clear()
         self.tracer.start()
         backend = False
@@ -280,7 +315,11 @@ class ProfilerSession:
                 self.profiler_start(self.log_dir)
                 backend = True
             except Exception:
-                pass        # devtime spans still capture device intervals
+                pass        # host spans are still captured
+        if backend and self.annotate is not None:
+            self.mark_t = time.perf_counter()
+            with self.annotate(PROFILE_MARK):
+                pass
         self.active = True
         return {"log_dir": self.log_dir, "backend_profiler": backend}
 
@@ -293,7 +332,6 @@ class ProfilerSession:
                 self.profiler_stop()
             except Exception:
                 pass
-        self.devtimer.enabled = self._was_enabled
         self.tracer.stop()
         self.active = False
         return {"log_dir": self.log_dir,
@@ -304,9 +342,10 @@ class ProfilerSession:
 
     def collect_chrome_events(self) -> list:
         """Device-thread slices from the backend profiler's Chrome trace
-        (`plugins/profile/*/ *.trace.json.gz`), rebased to the host
-        clock. Best-effort: an absent or unreadable capture yields []."""
-        if not self.log_dir:
+        (`plugins/profile/*/ *.trace.json.gz`), mapped to the host clock
+        through the clock mark. Best-effort: an absent or unreadable
+        capture, or one without the mark, yields []."""
+        if not self.log_dir or self.mark_t is None:
             return []
         pats = os.path.join(self.log_dir, "**", "*.trace.json.gz")
         events: list = []
@@ -317,34 +356,41 @@ class ProfilerSession:
             except Exception:
                 continue
             evs = doc.get("traceEvents", [])
-            threads = {}        # (pid, tid) -> thread name
-            for e in evs:
-                if e.get("ph") == "M" and e.get("name") == "thread_name":
-                    threads[(e.get("pid"), e.get("tid"))] = \
-                        e.get("args", {}).get("name", "")
-            dev_tids = {k for k, v in threads.items()
-                        if any(m in v for m in _DEVICE_THREAD_MARKERS)}
-            picked = [e for e in evs
-                      if e.get("ph") == "X"
-                      and (e.get("pid"), e.get("tid")) in dev_tids
-                      and not any(e.get("name", "").startswith(n)
-                                  for n in _NOISE_EVENTS)]
-            if not picked:
+            mark = [e["ts"] for e in evs if e.get("ph") == "X"
+                    and e.get("name") == PROFILE_MARK]
+            if not mark:
                 continue
-            ts0 = min(e["ts"] for e in picked)
-            base_us = self.host_t0 * 1e6
-            for e in picked:
-                tname = threads[(e.get("pid"), e.get("tid"))]
+            base_us = self.mark_t * 1e6 - min(mark)
+            procs, threads = {}, {}     # pid / (pid, tid) -> name
+            for e in evs:
+                if e.get("ph") != "M":
+                    continue
+                name = e.get("args", {}).get("name", "")
+                if e.get("name") == "process_name":
+                    procs[e.get("pid")] = name
+                elif e.get("name") == "thread_name":
+                    threads[(e.get("pid"), e.get("tid"))] = name
+            tracks = {}         # device thread -> track name
+            for (pid, tid), tname in threads.items():
+                pname = procs.get(pid, "")
+                if any(m in tname for m in _DEVICE_THREAD_MARKERS):
+                    tracks[(pid, tid)] = tname.split("/")[0]
+                elif any(m in pname for m in _DEVICE_THREAD_MARKERS):
+                    tracks[(pid, tid)] = f"{pname} {tname}"
+            for e in evs:
+                key = (e.get("pid"), e.get("tid"))
+                if e.get("ph") != "X" or key not in tracks or any(
+                        e.get("name", "").startswith(n)
+                        for n in _NOISE_EVENTS):
+                    continue
                 events.append({
-                    "track": DEVICE_TRACK_PREFIX + "xla "
-                             + tname.split("/")[0],
+                    "track": DEVICE_TRACK_PREFIX + "xla " + tracks[key],
                     "name": e.get("name", "?"),
-                    "ts_us": base_us + (e["ts"] - ts0),
+                    "ts_us": base_us + e["ts"],
                     "dur_us": float(e.get("dur", 0.0)),
                 })
         return events
 
     def state(self) -> dict:
         return {"active": self.active, "log_dir": self.log_dir,
-                "backend_bound": self.profiler_start is not None,
-                "device_timing": self.devtimer.enabled}
+                "backend_bound": self.profiler_start is not None}

@@ -11,10 +11,14 @@ Each span, on exit, adds its duration to the per-phase counter pair
 (`repro_step_phase_seconds_total{phase=...}` +
 `repro_step_phase_calls_total{phase=...}`), observes the per-phase
 histogram, and — only while a trace capture is active — records a
-Chrome-trace complete event. The span's measured duration (`sp.dur`) is
-what callers feed into the legacy per-slot accounting, so EngineStats
-and the registry are two views of the SAME perf_counter bracket and can
-never drift apart.
+Chrome-trace complete event and opens a profiler host annotation of the
+same name (`ProfilerSession.open`), so the phases appear in the
+profiler's own trace beside the device ops. The step loop wraps each
+step in `step_span(n)`: the phase spans recorded inside it carry the
+step's number in their trace args. The span's measured duration
+(`sp.dur`) is what callers feed into the legacy per-slot accounting, so
+EngineStats and the registry are two views of the SAME perf_counter
+bracket and can never drift apart.
 
 Disabled fast path: `Telemetry(enabled=False).span(...)` returns one
 shared `_NullSpan` whose __enter__/__exit__ do nothing and whose `dur`
@@ -42,8 +46,8 @@ from .trace import Tracer
 # decode step's wall time splits between host grammar work, the two
 # kernel families, and device time hidden under host work by the
 # overlap engine. Host-phase spans supply the grammar term; devtime
-# brackets supply the kernel terms when device timing is on (bench /
-# profile mode), falling back to dispatch-span lower bounds in serving.
+# brackets supply the kernel terms when device timing is on (bench
+# mode), falling back to dispatch-span lower bounds in serving.
 ATTR_HOST_GRAMMAR_PHASES = ("ci_lookup", "cd_check", "host_oracle",
                             "plan", "feed_build")
 # context-split sub-components of host_grammar: the precomputed-row
@@ -79,7 +83,7 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("tele", "phase", "track", "args", "t0", "dur")
+    __slots__ = ("tele", "phase", "track", "args", "t0", "dur", "_ann")
 
     def __init__(self, tele: "Telemetry", phase: str,
                  track: Optional[str], args: Optional[dict]):
@@ -89,21 +93,94 @@ class _Span:
         self.args = args
         self.t0 = 0.0
         self.dur = 0.0
+        self._ann = None
 
     def __enter__(self):
+        tele = self.tele
+        if tele.tracer.active:
+            self._ann = tele.profiler.open(self.phase)
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.dur = dur = time.perf_counter() - self.t0
         tele = self.tele
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         sec, calls, hist = tele._phase(self.phase)
         sec.inc(dur)
         calls.inc()
         hist.observe(dur)
         if tele.tracer.active:
+            args, n = self.args, tele.step_n
+            if n is not None:
+                args = {**args, "step": n} if args else {"step": n}
             tele.tracer.add(self.track or self.phase, self.phase,
-                            self.t0, dur, self.args)
+                            self.t0, dur, args)
+        return False
+
+
+class _NullStepSpan:
+    """Shared no-op step span: telemetry disabled. dur is always 0.0."""
+    __slots__ = ()
+    dur = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, admitted: int) -> None:
+        pass
+
+    def drop(self) -> None:
+        pass
+
+
+NULL_STEP_SPAN = _NullStepSpan()
+
+
+class _StepSpan:
+    """One step of the step loop: admission through commit. The phase
+    spans recorded inside it carry its number (`Telemetry.step_n`);
+    while tracing it lands on the `step` track and opens the profiler's
+    step annotation. `note()` records how many requests it admitted;
+    `drop()` records nothing (the iteration found no slot to step)."""
+    __slots__ = ("tele", "n", "admitted", "dropped", "t0", "dur", "_ann")
+
+    def __init__(self, tele: "Telemetry", n: int):
+        self.tele = tele
+        self.n = n
+        self.admitted = 0
+        self.dropped = False
+        self.t0 = 0.0
+        self.dur = 0.0
+        self._ann = None
+
+    def note(self, admitted: int) -> None:
+        self.admitted = admitted
+
+    def drop(self) -> None:
+        self.dropped = True
+
+    def __enter__(self):
+        tele = self.tele
+        tele.step_n = self.n
+        if tele.tracer.active:
+            self._ann = tele.profiler.open_step(self.n)
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = dur = time.perf_counter() - self.t0
+        tele = self.tele
+        tele.step_n = None
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if tele.tracer.active and not self.dropped:
+            tele.tracer.add("step", "step", self.t0, dur,
+                            {"step": self.n, "admitted": self.admitted})
         return False
 
 
@@ -122,8 +199,9 @@ class Telemetry:
                           if self.enabled else NullLifecycle())
         self.t_start = time.perf_counter()
         self._phases: dict = {}
+        self.step_n: Optional[int] = None   # the step being run, if any
         self.devtime = DeviceTimer(self.registry, self.tracer)
-        self.profiler = ProfilerSession(self.devtime, self.tracer)
+        self.profiler = ProfilerSession(self.tracer)
         if self.enabled:
             self.registry.gauge(
                 "repro_uptime_seconds", "seconds since telemetry start",
@@ -200,6 +278,13 @@ class Telemetry:
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, phase, track, args)
+
+    def step_span(self, n: int):
+        """Bracket step n of the step loop (`_StepSpan`); the shared
+        no-op when disabled."""
+        if not self.enabled:
+            return NULL_STEP_SPAN
+        return _StepSpan(self, n)
 
     def device_span(self, fn: str):
         """Device-interval bracket around a jitted call. No-op unless

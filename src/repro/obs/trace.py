@@ -9,9 +9,13 @@ the most recent `capacity` events, never an unbounded buffer.
 `export_chrome()` emits the Chrome trace-event JSON flavour that
 ui.perfetto.dev (and chrome://tracing) loads directly: complete events
 (`"ph": "X"`) with microsecond timestamps, one *thread track per
-span-track name* — loop phases each get their own track, every slot gets
-a `slot N` track carrying its requests' lifetime spans and token
-instants — named via `thread_name` metadata events.
+span-track name* — the `step` track first, one span per step of the
+loop (admission through commit, args `step` and `admitted`), then each
+phase on its own track (phase spans carry their step's number in args,
+`admit` spans the request's `rid`), and every slot a `slot N` track
+carrying its requests' lifetime spans, `admit_to_first_token` spans and
+token instants — named via `thread_name` metadata events. An event is
+the 6-tuple `(ph, track, name, t0, dur, args)`; identifiers go in args.
 
 Timestamps are raw `time.perf_counter()` values captured by the spans
 themselves; the exporter rebases them to the earliest event so the trace
@@ -33,8 +37,7 @@ _TRACK_ORDER = ("step", "admit", "plan", "feed_build", "ci_lookup",
                 "cd_check", "mask_dispatch", "forward",
                 "overlap_forward",
                 "select_resolve", "host_oracle", "opportunistic",
-                "device:forward", "device:overlap_forward",
-                "device:mask_sample")
+                "device:forward", "device:mask_sample")
 
 
 class Tracer:

@@ -129,6 +129,8 @@ class RequestState:
     deadline_at: Optional[float] = None     # perf_counter() expiry
     admit_t: Optional[float] = None         # perf_counter() at admission
                                             # (telemetry: slot trace span)
+    first_commit_t: Optional[float] = None  # perf_counter() at the commit
+                                            # of the first token
 
 
 @dataclass
@@ -143,13 +145,24 @@ class EngineStats:
                                             # steps (one per engine step; a
                                             # discarded speculative forward
                                             # is extra device work counted
-                                            # as overlap_dispatched -
-                                            # overlap_hits, not here)
+                                            # in overlap_discarded, not here)
     batch_slots: int = 0
     mesh_devices: int = 1                   # tensor-parallel mesh size
     # --- host/device overlap (serving/loop.py::DenseMode) ---
     overlap_dispatched: int = 0             # speculative forwards launched
     overlap_hits: int = 0                   # ...that the next step consumed
+    overlap_discarded: int = 0              # ...dropped unused (an
+                                            # admission, or an unclean step)
+    # --- the exact oracle (_select_resolve) ---
+    oracle_accepted: int = 0                # device picks the oracle passed
+    oracle_rejected: int = 0                # ...and rejected
+    resample_calls: int = 0                 # device resample dispatches
+    exact_fallbacks: int = 0                # slots drawn by the exact filter
+    # --- the step loop (serving/loop.py::StepLoop) ---
+    steps_timed: int = 0                    # steps: admission to commit
+    step_s: float = 0.0                     # their wall seconds
+    admit_steps: int = 0                    # steps that admitted a request
+    admit_step_s: float = 0.0               # their wall seconds
     # --- speculation (generate_speculative) ---
     jump_tokens: int = 0                    # emitted with zero model calls
     draft_proposed: int = 0
@@ -163,7 +176,7 @@ class EngineStats:
     kv_evictions: int = 0                   # cold pages evicted
     kv_cow_copies: int = 0                  # copy-on-write device copies
     # --- device-time attribution (obs/devtime; nonzero only when the
-    # run had device timing on, i.e. bench/profile mode) ---
+    # run had device timing on, i.e. bench mode) ---
     device_forward_s: float = 0.0           # synced forward intervals
     device_mask_sample_s: float = 0.0       # synced mask+sample intervals
     overlap_hidden_s: float = 0.0           # device time hidden under
@@ -252,7 +265,7 @@ class Engine:
         EngineStats then read 0. Token streams are identical either
         way — instrumentation wraps host-side work only and never
         adds a device synchronization.
-        devtime: bench/profile mode — device-span brackets around the
+        devtime: bench mode — device-span brackets around the
         jitted calls sync on exit (obs/devtime.py), so EngineStats and
         /stats carry true device intervals instead of dispatch lower
         bounds. OFF for serving: the default preserves the no-sync
@@ -277,7 +290,7 @@ class Engine:
         self.trunk_shard = bool(trunk_shard)
         self.overlap = bool(overlap)
         self.telemetry_enabled = bool(telemetry)
-        # bench/profile mode: step loops sync devtime brackets on exit
+        # bench mode: step loops sync devtime brackets on exit
         # (the documented exception to the serving no-sync contract)
         self.devtime_enabled = bool(devtime)
         if mesh is not None:
@@ -380,7 +393,7 @@ class Engine:
     def _note_jit_cost(self, tele, name: str, fn, *args) -> None:
         """Lazily attach static roofline terms (distributed/hlo_cost
         over the compiled HLO) for a jitted fn to the devtime registry —
-        once per fn name, only in bench/profile mode, at the exact
+        once per fn name, only in bench mode, at the exact
         shapes the caller just dispatched (the lowering hits the
         compilation cache, so this is a walk, not a recompile). Sharded
         closures and anything else without .lower() are skipped."""
@@ -632,7 +645,9 @@ class Engine:
         B = self.slots
         committed: dict[int, int] = {}
         pending = set(pending)
-        ctr = {"mask_computations": 0, "opportunistic_hits": 0}
+        ctr = {"mask_computations": 0, "opportunistic_hits": 0,
+               "oracle_accepted": 0, "oracle_rejected": 0,
+               "resample_calls": 0, "exact_fallbacks": 0}
         salts = np.array([slot_state[b].steps if slot_state[b] else 0
                           for b in range(B)], np.uint32)
         ctx = _SelectCtx(committed=committed, pending=pending, ctr=ctr,
@@ -717,7 +732,7 @@ class Engine:
                                  temp.copy(), top_k.copy(),
                                  top_p.copy(), noise)
                     cost_fn = self._fused_sample
-            # host span stays dispatch-only; in bench/profile mode the
+            # host span stays dispatch-only; in bench mode the
             # device bracket blocks on the sampled ids here
             dv.done((ctx.ids, ctx.ok))
         self._note_jit_cost(obs, "mask_sample", cost_fn, *cost_args)
@@ -803,14 +818,17 @@ class Engine:
                     t = int(ids_h[b])
                     if t == EOS_ID or st.constraint.is_valid_extension(
                             st.generated, t):
+                        ctr["oracle_accepted"] += 1
                         committed[b] = t
                         pending.discard(b)
                     else:
+                        ctr["oracle_rejected"] += 1
                         redo[b] = True
                         ban[b] = t
                 if not redo.any():
                     break
                 ctx.clean = False
+                ctr["resample_calls"] += 1
                 keys = self._step_keys(seeds, salts, attempt)
                 masked, ids, ok = self._resample(
                     masked, jnp.asarray(ban), jnp.asarray(redo),
@@ -822,6 +840,7 @@ class Engine:
             # exact-filter fallback for slots that never validated
             for b in sorted(pending):
                 ctx.clean = False
+                ctr["exact_fallbacks"] += 1
                 st = slot_state[b]
                 nxt = self._fallback_exact(st, np.asarray(masked[b]),
                                            st.steps)

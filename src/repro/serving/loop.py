@@ -172,7 +172,7 @@ class StepLoop:
             Telemetry(enabled=getattr(engine, "telemetry_enabled", True))
         # bind the jax sync/profiler capabilities (devbridge is the one
         # sanctioned jax touchpoint for obs); device timing itself stays
-        # OFF unless the engine was built for bench/profile mode
+        # OFF unless the engine was built for bench mode
         _attach_devbridge(self.tele)
         if getattr(engine, "devtime_enabled", False):
             self.tele.devtime.enabled = True
@@ -242,6 +242,43 @@ class StepLoop:
         self.c_overlap_probe = reg.counter(
             "repro_overlap_forwards_total", "overlap gate outcomes",
             {"outcome": "probe"})
+        # discarded = dispatched but never consumed, counted where it is
+        # dropped: an admission invalidated the pending forward, or the
+        # host changed the step's outcome. dispatched - hit - discarded
+        # is 1 while a forward is in flight, else 0.
+        self.c_overlap_discard = {
+            reason: reg.counter(
+                "repro_overlap_forwards_total", "overlap gate outcomes",
+                {"outcome": "discarded", "reason": reason})
+            for reason in ("admit", "unclean")}
+        # the exact oracle (_select_resolve): device picks checked on the
+        # host, resample dispatches, exact-filter fallbacks
+        self.c_oracle = {
+            outcome: reg.counter(
+                "repro_oracle_checks_total",
+                "device picks validated by the exact oracle",
+                {"outcome": outcome})
+            for outcome in ("accepted", "rejected")}
+        self.c_resample = reg.counter(
+            "repro_resample_calls_total",
+            "device resample dispatches after an oracle rejection")
+        self.c_fallback = reg.counter(
+            "repro_exact_fallbacks_total",
+            "slots drawn by the host exact filter")
+        # steps of the loop (admission through commit), by whether they
+        # admitted a request: counts always live, seconds from the step
+        # span (0 with telemetry disabled)
+        self.c_loop_steps = {
+            a: reg.counter("repro_loop_steps_total",
+                           "step-loop steps, by whether they admitted",
+                           {"admitting": a})
+            for a in ("true", "false")}
+        self.c_loop_step_s = {
+            a: reg.counter("repro_loop_step_seconds_total",
+                           "wall seconds of step-loop steps, by whether "
+                           "they admitted", {"admitting": a})
+            for a in ("true", "false")}
+        self.step_no = 0
         if self.tele.enabled:
             reg.gauge("repro_queue_depth", "requests waiting for a slot",
                       fn=lambda: float(len(self.source)))
@@ -258,7 +295,7 @@ class StepLoop:
         return [b for b in range(self.B) if self.slot_state[b] is not None]
 
     def admit(self, b: int, req) -> None:
-        with self.tele.span("admit") as sp:
+        with self.tele.span("admit", args={"rid": req.rid}) as sp:
             st = self.mode.admit(self, b, req)
             self.slot_state[b] = st
             self.seeds[b] = np.uint32(req.seed & 0xFFFFFFFF)
@@ -301,6 +338,12 @@ class StepLoop:
         self.c_tokens.inc()
         self.tele.lifecycle.on_token(st.req.rid)
         tr = self.tele.tracer
+        if st.first_commit_t is None:
+            st.first_commit_t = time.perf_counter()
+            if tr.active and st.admit_t is not None:
+                tr.add(f"slot {st.slot}", "admit_to_first_token",
+                       st.admit_t, st.first_commit_t - st.admit_t,
+                       {"rid": st.req.rid})
         if tr.active:
             tr.instant(f"slot {st.slot}", "token", time.perf_counter(),
                        {"id": int(token)})
@@ -375,49 +418,74 @@ class StepLoop:
         while True:
             self._drain_controls()
             self._sweep()
-            for b in range(self.B):
-                if self.slot_state[b] is not None:
-                    continue
-                # pop-then-gate (never len/peek-then-pop): cancel
-                # withdrawal runs on another thread, so the queue can
-                # empty between a check and a pop
-                req = self.source.try_pop()
-                if req is None:
-                    break
-                if not self.mode.can_admit_req(self, req):
-                    self.source.push_front(req)
-                    break
-                self.admit(b, req)
-            active = self.active()
-            if not active:
-                req = self.source.try_pop()
-                if req is not None:
-                    if self.mode.can_admit_req(self, req):
-                        # admittable after all (e.g. submitted after the
-                        # admission sweep): next iteration takes it
-                        self.source.push_front(req)
-                        continue
-                    # no slot can ever take this request (paged pool too
-                    # small): strict sources raise, live sources fail
-                    # the request gracefully and keep serving
-                    if self.source.closed:
-                        raise PoolExhausted(
-                            "KV pool too small for the next request's "
-                            "prompt")
-                    self.fail_request(req, "kv_oom")
-                    continue
-                if self.source.closed:
-                    break
-                # idle: the queue is empty, so any memoized prompt ids
-                # belong to withdrawn/failed requests — drop them (rids
-                # are never reused, so they could only accumulate)
-                self.ids_cache.clear()
-                self.mode.on_idle(self)
-                self.source.wait_for_work(idle_wait)
+            if self._step():
                 continue
-            self.mode.step(self, active)
+            # no slot is active: nothing is running or was admitted
+            req = self.source.try_pop()
+            if req is not None:
+                if self.mode.can_admit_req(self, req):
+                    # admittable after all (e.g. submitted after the
+                    # admission sweep): next iteration takes it
+                    self.source.push_front(req)
+                    continue
+                # no slot can ever take this request (paged pool too
+                # small): strict sources raise, live sources fail
+                # the request gracefully and keep serving
+                if self.source.closed:
+                    raise PoolExhausted(
+                        "KV pool too small for the next request's "
+                        "prompt")
+                self.fail_request(req, "kv_oom")
+                continue
+            if self.source.closed:
+                break
+            # idle: the queue is empty, so any memoized prompt ids
+            # belong to withdrawn/failed requests — drop them (rids
+            # are never reused, so they could only accumulate)
+            self.ids_cache.clear()
+            self.mode.on_idle(self)
+            self.source.wait_for_work(idle_wait)
         return (self.all_states, self.stats()) if self.keep_states \
             else (None, self.stats())
+
+    def _admit_free(self) -> int:
+        """Fill free slots from the source; returns how many it admitted."""
+        n = 0
+        for b in range(self.B):
+            if self.slot_state[b] is not None:
+                continue
+            # pop-then-gate (never len/peek-then-pop): cancel withdrawal
+            # runs on another thread, so the queue can empty between a
+            # check and a pop
+            req = self.source.try_pop()
+            if req is None:
+                break
+            if not self.mode.can_admit_req(self, req):
+                self.source.push_front(req)
+                break
+            self.admit(b, req)
+            n += 1
+        return n
+
+    def _step(self) -> bool:
+        """One step under one `step` span: admission, then the mode's
+        step body. False, with nothing recorded, where no slot is active
+        after admission (the caller's idle path)."""
+        if not self.active() and not len(self.source):
+            return False
+        with self.tele.step_span(self.step_no) as sp:
+            n = self._admit_free()
+            active = self.active()
+            if not active:
+                sp.drop()
+                return False
+            self.mode.step(self, active)
+            sp.note(n)
+        key = "true" if n else "false"
+        self.c_loop_steps[key].inc()
+        self.c_loop_step_s[key].inc(sp.dur)
+        self.step_no += 1
+        return True
 
     # ------------------------------ stats ------------------------------
 
@@ -450,6 +518,17 @@ class StepLoop:
             plan_time=tele.phase_seconds("plan"),
             overlap_dispatched=int(self.c_overlap_disp.value),
             overlap_hits=int(self.c_overlap_hit.value),
+            overlap_discarded=int(sum(
+                c.value for c in self.c_overlap_discard.values())),
+            oracle_accepted=int(self.c_oracle["accepted"].value),
+            oracle_rejected=int(self.c_oracle["rejected"].value),
+            resample_calls=int(self.c_resample.value),
+            exact_fallbacks=int(self.c_fallback.value),
+            steps_timed=int(sum(c.value
+                                for c in self.c_loop_steps.values())),
+            step_s=sum(c.value for c in self.c_loop_step_s.values()),
+            admit_steps=int(self.c_loop_steps["true"].value),
+            admit_step_s=self.c_loop_step_s["true"].value,
             device_forward_s=(tele.devtime.seconds("forward")
                               + tele.devtime.seconds("overlap_forward")),
             device_mask_sample_s=tele.devtime.seconds("mask_sample"),
@@ -461,6 +540,10 @@ class StepLoop:
     def add_select_ctr(self, ctr: dict) -> None:
         self.c_mask_comp.inc(ctr["mask_computations"])
         self.c_opp_hits.inc(ctr["opportunistic_hits"])
+        self.c_oracle["accepted"].inc(ctr["oracle_accepted"])
+        self.c_oracle["rejected"].inc(ctr["oracle_rejected"])
+        self.c_resample.inc(ctr["resample_calls"])
+        self.c_fallback.inc(ctr["exact_fallbacks"])
 
 
 # ------------------------------- modes ---------------------------------
@@ -532,6 +615,8 @@ class DenseMode(_ModeBase):
         loop.feed_pos[b] = st.pos - 1
         # the inserted prefill caches invalidate any in-flight
         # speculative forward for this slot
+        if self.pending_logits is not None:
+            loop.c_overlap_discard["admit"].inc()
         self.pending_logits = None
         self._spec_disp_t = None
         return st
@@ -549,7 +634,7 @@ class DenseMode(_ModeBase):
             # overlap-hidden attribution: the host-work window between
             # the speculative dispatch finishing and this consumption is
             # device time the overlap hid. Clamp to the latest measured
-            # forward interval when devtime has one (bench/profile);
+            # forward interval when devtime has one (bench mode);
             # otherwise the window itself is the documented upper bound.
             # Never sync the speculative forward — that would serialize
             # the very overlap being measured.
@@ -571,7 +656,7 @@ class DenseMode(_ModeBase):
                     logits, self.caches = eng._decode(
                         eng.params, self.caches, tok_dev, pos_dev)
                 dv.done(logits)     # host span stays dispatch-only; the
-                # device bracket blocks here in bench/profile mode
+                # device bracket blocks here in bench mode
             eng._note_jit_cost(tele, "forward", eng._decode, eng.params,
                                self.caches, tok_dev, pos_dev)
         loop.c_decode_steps.inc()
@@ -621,10 +706,12 @@ class DenseMode(_ModeBase):
         # on, and `admit()` drops the pending forward if the freed slot
         # is refilled. Discarded forwards are harmless: position-
         # addressed caches rewrite idempotently.
-        if spec_logits is not None and ctx.clean and \
-                set(committed) == set(active):
+        if spec_logits is None:
+            return
+        if ctx.clean and set(committed) == set(active):
             self.pending_logits = spec_logits
         else:
+            loop.c_overlap_discard["unclean"].inc()
             self._spec_disp_t = None    # discarded forward hides nothing
 
     def _speculate_now(self, loop) -> bool:

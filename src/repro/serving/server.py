@@ -37,7 +37,7 @@ Observability surfaces (docs/observability.md):
   GET  /metrics  -> Prometheus text exposition: step-phase seconds,
                     TTFT/ITL/queue-wait histograms, token/mask/overlap
                     counters, KV pool gauges, device-attribution
-                    counters and (in profile mode) device intervals.
+                    counters and (in bench mode) device intervals.
   GET  /stats    -> the same data as one JSON snapshot (plus request
                     p50/p99 summaries, build identity, the per-step
                     attribution split and trace-buffer state).
@@ -46,14 +46,12 @@ Observability surfaces (docs/observability.md):
                     ring buffer; dump returns Chrome trace-event JSON
                     (loadable in ui.perfetto.dev) without stopping.
   POST /profile  -> {"action": "start" | "stop" | "dump"}. Live
-                    profiler capture: start flips device spans into
-                    sync-on-exit mode (the documented profile-mode
-                    exception to the serving no-sync contract), starts
-                    trace capture AND a jax.profiler trace; dump (after
-                    stop) returns ONE Chrome trace document with the
-                    host phase spans, the synced device brackets, and
-                    the profiler's kernel-thread slices merged on a
-                    shared host-clock timeline.
+                    profiler capture: start starts trace capture AND a
+                    jax.profiler trace and writes a clock mark, with no
+                    device sync; dump (after stop) returns ONE Chrome
+                    trace document with the host step and phase spans
+                    and the profiler's device slices, placed on the
+                    host clock through the mark.
 
 The HTTP layer is deliberately tiny (HTTP/1.1, Content-Length bodies,
 chunked responses); production fronting belongs in a real proxy — this
@@ -319,8 +317,8 @@ class EngineServer:
                         chunked=False, body=json.dumps(out).encode())
 
     async def _profile(self, writer, body: bytes) -> None:
-        """Live profiler capture: devtime sync-on-exit + jax.profiler
-        trace, dumped as one merged host+device Chrome timeline."""
+        """Live profiler capture: a jax.profiler trace beside the span
+        ring, dumped as one merged host+device Chrome timeline."""
         try:
             spec = json.loads(body.decode() or "{}")
         except (ValueError, UnicodeDecodeError):

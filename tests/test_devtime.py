@@ -6,19 +6,22 @@ Contract groups (docs/observability.md §Device-time attribution):
     injected sync capability is NEVER invoked: `DeviceTimer.sync_calls`
     stays 0 across a full engine run, and `span()` hands back the shared
     `NULL_DEV_SPAN` (PR 7's no-sync contract holds verbatim);
-  * **measured brackets** — in bench/profile mode the span syncs on the
+  * **measured brackets** — in bench mode the span syncs on the
     arrays passed to `done()` and records a true device interval into
     the `repro_device_*` families and the `device:<fn>` trace track;
-  * **profiler session** — the `POST /profile` state machine flips the
-    timer into sync-on-exit mode for exactly the capture window,
-    tolerates an unbound/broken backend profiler, and rebases backend
-    Chrome events onto the host clock for the merged export;
+  * **profiler session** — the `POST /profile` state machine leaves the
+    device timer alone (no sync-on-exit: that would serialize the
+    overlap the capture shows), tolerates an unbound/broken backend
+    profiler, and maps backend Chrome events onto the host clock
+    through its clock mark for the merged export;
   * **attribution math** — the step wall-time split prefers synced
     device seconds per kernel family and falls back to host dispatch
     spans, reporting which source produced each number;
   * **identity** — devtime on vs off is token-for-token identical
     (observation may never perturb decoding).
 """
+import contextlib
+import glob
 import gzip
 import json
 import os
@@ -28,8 +31,9 @@ import jax
 import pytest
 
 from repro.core.decoding import DecodeConfig
-from repro.obs import (DEVICE_TRACK_PREFIX, NULL_DEV_SPAN, DeviceTimer,
-                       MetricsRegistry, ProfilerSession, Telemetry, Tracer)
+from repro.obs import (DEVICE_TRACK_PREFIX, NULL_DEV_SPAN, PROFILE_MARK,
+                       DeviceTimer, MetricsRegistry, ProfilerSession,
+                       Telemetry, Tracer)
 from repro.serving.engine import Engine, Request
 
 MAX_LEN = 160
@@ -148,16 +152,31 @@ def test_set_cost_surfaces_roofline_inputs():
 
 # ======================= unit: ProfilerSession ========================
 
+def _session(tr, mark=True):
+    """A ProfilerSession over a no-op backend; with `mark`, an
+    annotation factory, so start() writes the clock mark."""
+    ps = ProfilerSession(tr)
+    ps.bind(lambda d: None, lambda: None,
+            (lambda name: contextlib.nullcontext()) if mark else None)
+    return ps
+
+
 def test_profiler_session_state_machine():
-    dt, tr = _timer()
+    tele = Telemetry(enabled=True)
+    dt, tr = tele.devtime, tele.tracer
     dt.bind(lambda out: out)
-    ps = ProfilerSession(dt, tr)
+    ps = tele.profiler
     assert ps.state()["active"] is False
     with pytest.raises(RuntimeError):
         ps.stop()                               # stop before start
     info = ps.start()
-    assert ps.active and dt.enabled and tr.active
+    assert ps.active and tr.active
+    assert not dt.enabled                       # no sync-on-exit
+    with tele.device_span("forward") as dv:     # a capture never syncs
+        dv.done(1)
+    assert dt.sync_calls == 0
     assert info["backend_profiler"] is False    # no backend bound
+    assert ps.mark_t is None                    # ...so no clock mark
     with pytest.raises(RuntimeError):
         ps.start()                              # double start
     out = ps.stop()
@@ -167,60 +186,140 @@ def test_profiler_session_state_machine():
 
 
 def test_profiler_session_restores_prior_devtime_mode():
-    dt, tr = _timer()
-    dt.bind(lambda out: out)
-    dt.enabled = True                           # bench mode before capture
-    ps = ProfilerSession(dt, tr)
-    ps.start()
-    ps.stop()
-    assert dt.enabled is True                   # restored, not reset
+    # the capture never changes the mode, so the one after it is the
+    # one before it, on or off
+    for before in (True, False):
+        dt, tr = _timer()
+        dt.bind(lambda out: out)
+        dt.enabled = before
+        ps = _session(tr)
+        ps.start()
+        assert dt.enabled is before
+        ps.stop()
+        assert dt.enabled is before
 
 
 def test_profiler_session_tolerates_broken_backend():
     dt, tr = _timer()
-    ps = ProfilerSession(dt, tr)
+    ps = ProfilerSession(tr)
 
     def broken_start(log_dir):
         raise OSError("no backend")
-    ps.bind(broken_start, lambda: None)
+    ps.bind(broken_start, lambda: None,
+            lambda name: contextlib.nullcontext())
     info = ps.start()
     assert info["backend_profiler"] is False    # swallowed, still capturing
-    assert ps.active
+    assert ps.active and ps.mark_t is None      # no capture, no mark
     ps.stop()
 
 
+def _write_capture(root, events, threads, procs=()):
+    """A backend profiler's Chrome trace under root/plugins/profile."""
+    meta = [{"ph": "M", "name": "thread_name", "pid": pid, "tid": tid,
+             "args": {"name": name}}
+            for (pid, tid), name in threads.items()]
+    meta += [{"ph": "M", "name": "process_name", "pid": pid,
+              "args": {"name": name}} for pid, name in dict(procs).items()]
+    d = root / "plugins" / "profile" / "run1"
+    os.makedirs(d)
+    with gzip.open(d / "host.trace.json.gz", "wt") as f:
+        json.dump({"traceEvents": meta + events}, f)
+
+
 def test_collect_chrome_events_parses_and_rebases(tmp_path):
-    dt, tr = _timer()
-    ps = ProfilerSession(dt, tr)
-    ps.bind(lambda d: None, lambda: None)
+    _, tr = _timer()
+    ps = _session(tr)
     ps.start(log_dir=str(tmp_path))
-    # synthetic backend capture: one device thread, one python thread,
-    # one noise slice — only the device kernel slice must survive
-    doc = {"traceEvents": [
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 2,
-         "args": {"name": "TFRT XLATfrtCpuClient/0"}},
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 3,
-         "args": {"name": "python main"}},
+    assert ps.host_t0 <= ps.mark_t
+    # synthetic backend capture: the clock mark on the python thread,
+    # one device thread, one noise slice — only the device kernel slice
+    # must survive, placed through the mark
+    _write_capture(tmp_path, [
+        {"ph": "X", "pid": 1, "tid": 3, "name": PROFILE_MARK,
+         "ts": 4000.0, "dur": 2.0},
         {"ph": "X", "pid": 1, "tid": 2, "name": "fusion.1",
          "ts": 5000.0, "dur": 40.0},
         {"ph": "X", "pid": 1, "tid": 2, "name": "ThunkExecutor work",
          "ts": 5010.0, "dur": 1.0},
         {"ph": "X", "pid": 1, "tid": 3, "name": "host_python_frame",
          "ts": 5000.0, "dur": 500.0},
-    ]}
-    d = tmp_path / "plugins" / "profile" / "run1"
-    os.makedirs(d)
-    with gzip.open(d / "host.trace.json.gz", "wt") as f:
-        json.dump(doc, f)
+    ], {(1, 2): "TFRT XLATfrtCpuClient/0", (1, 3): "python main"})
     ps.stop()
     evs = ps.collect_chrome_events()
     assert len(evs) == 1
     ev = evs[0]
     assert ev["name"] == "fusion.1"
     assert ev["track"].startswith(DEVICE_TRACK_PREFIX + "xla ")
-    # earliest picked event is pinned to the host-clock capture start
-    assert ev["ts_us"] == pytest.approx(ps.host_t0 * 1e6)
+    assert ev["ts_us"] == pytest.approx(ps.mark_t * 1e6 + 1000.0)
     assert ev["dur_us"] == 40.0
+    # without the mark there is no clock to map through
+    ps2 = _session(tr, mark=False)
+    ps2.start(log_dir=str(tmp_path))
+    ps2.stop()
+    assert ps2.mark_t is None and ps2.collect_chrome_events() == []
+
+
+def test_collect_chrome_events_places_late_first_event_by_mark(tmp_path):
+    """The first device op comes 80 ms after start() (a step's worth of
+    host work first): it lands at its own host time, not at host_t0."""
+    _, tr = _timer()
+    ps = _session(tr)
+    ps.start(log_dir=str(tmp_path))
+    _write_capture(tmp_path, [
+        {"ph": "X", "pid": 7, "tid": 1, "name": PROFILE_MARK,
+         "ts": 100.0, "dur": 1.0},
+        {"ph": "X", "pid": 9, "tid": 4, "name": "jit_decode_step",
+         "ts": 80100.0, "dur": 66000.0},
+        {"ph": "X", "pid": 9, "tid": 5, "name": "fusion.3",
+         "ts": 80200.0, "dur": 500.0},
+    ], {(7, 1): "python", (9, 4): "XLA Modules", (9, 5): "XLA Ops"},
+        {9: "/device:TPU:0"})
+    ps.stop()
+    evs = {e["name"]: e for e in ps.collect_chrome_events()}
+    assert set(evs) == {"jit_decode_step", "fusion.3"}
+    step = evs["jit_decode_step"]
+    assert step["ts_us"] == pytest.approx(ps.mark_t * 1e6 + 80000.0)
+    assert step["ts_us"] - ps.host_t0 * 1e6 >= 80000.0
+    assert evs["fusion.3"]["ts_us"] - step["ts_us"] == pytest.approx(100.0)
+    assert step["track"] == DEVICE_TRACK_PREFIX + \
+        "xla /device:TPU:0 XLA Modules"
+
+
+def test_live_capture_places_device_slices_after_their_dispatch(tmp_path):
+    """A real jax.profiler capture on this backend, bound by devbridge:
+    the step and phase spans enter the profiler's trace, and the device
+    slices merged through the clock mark start after the span that
+    dispatched them, 50 ms into the capture."""
+    import jax.numpy as jnp
+
+    from repro.serving.devbridge import attach
+    tele = Telemetry(enabled=True)
+    attach(tele)
+    f = jax.jit(lambda x: (x @ x) * 0.5)
+    x = jnp.ones((256, 256), jnp.float32)
+    f(x).block_until_ready()
+    ps = tele.profiler
+    assert ps.start(log_dir=str(tmp_path))["backend_profiler"] is True
+    time.sleep(0.05)
+    with tele.step_span(0):
+        with tele.span("forward"):
+            y = f(x)
+        y.block_until_ready()
+    ps.stop()
+    fwd = next(e for e in tele.tracer._ring if e[2] == "forward")
+    evs = ps.collect_chrome_events()
+    assert evs and all(e["track"].startswith(DEVICE_TRACK_PREFIX + "xla ")
+                       for e in evs)
+    first = min(e["ts_us"] for e in evs)
+    assert first >= fwd[3] * 1e6 - 1000.0       # not pinned to host_t0
+    assert first - ps.host_t0 * 1e6 >= 40e3
+    assert max(e["ts_us"] + e["dur_us"] for e in evs) <= ps.host_t1 * 1e6
+    names = set()
+    for path in glob.glob(os.path.join(ps.log_dir, "**", "*.trace.json.gz"),
+                          recursive=True):
+        with gzip.open(path, "rt") as fh:
+            names |= {e.get("name") for e in json.load(fh)["traceEvents"]}
+    assert {PROFILE_MARK, "decode", "forward"} <= names
 
 
 def test_merged_export_aligns_host_and_device_tracks():
