@@ -8,12 +8,15 @@ Each kind implements:
 
 ctx is a dict: {"positions": [B,S] or None, "pos": scalar decode position,
 "image_embeds": [B,Ni,D] (vlm), "enc_out": [B,Se,D] (audio),
-"cache_len": static cache length, "window": per-layer window override}.
+"cache_len": static cache length, "window": per-layer window override,
+"layer": the scanned layer's index in dense decode, where the trunk
+carries each group's stacked caches whole and `cache` is the stack}.
 
 KV caches store rotated K plus a per-slot absolute-position array
 (`kv_pos`, −1 = empty) so ring-buffer (sliding-window) and linear caches
 share one masking rule: valid ⇔ 0 ≤ kv_pos ≤ q_pos (∧ q_pos − kv_pos <
-window).
+window). A cache line holds the head dim zero-padded to whole 128-lane
+tiles (`kv_width`); attention reads its first Dh lanes.
 """
 from __future__ import annotations
 
@@ -44,12 +47,33 @@ def _cache_len(cfg, ctx, seq_len):
     return min(L, w) if w else L
 
 
+LANES = 128                     # a TPU vector register's minor dim
+
+
+def kv_width(head_dim: int) -> int:
+    """Head width of a KV cache line: Dh rounded up to whole lanes. A
+    TPU lays out a [.., L, K, Dh] cache with Dh = 64 L-minor by default,
+    and the decode step would then relayout whole caches around its
+    in-place row writes; with a lane-multiple minor dim the default
+    layout is the one the writes and attention use in place. At Dh = 64
+    that doubles the cache's bytes, as any Dh-minor layout on the chip
+    does."""
+    return -(-head_dim // LANES) * LANES
+
+
+def _to_width(x, width: int):
+    """Zero-pad fresh K or V rows [.., Dh] to a cache line's width."""
+    pad = width - x.shape[-1]
+    return x if pad == 0 else jnp.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def init_kv_cache(cfg, batch, length, dtype, kv_heads=None):
     K = kv_heads or cfg.num_kv_heads
-    Dh = cfg.resolved_head_dim
+    W = kv_width(cfg.resolved_head_dim)
     return {
-        "k": jnp.zeros((batch, length, K, Dh), dtype),
-        "v": jnp.zeros((batch, length, K, Dh), dtype),
+        "k": jnp.zeros((batch, length, K, W), dtype),
+        "v": jnp.zeros((batch, length, K, W), dtype),
         "kv_pos": jnp.full((batch, length), -1, jnp.int32),
     }
 
@@ -89,9 +113,12 @@ def _self_attention_prefill(p, x, cfg, ctx):
         if "true_len" in ctx else S
     kv_pos = jnp.broadcast_to(jnp.where(take < limit, take, -1)[None, :],
                               (B, L))
+    W = cache["k"].shape[-1]
     cache = {
-        "k": cache["k"].at[:, slot].set(k[:, take].astype(cache["k"].dtype)),
-        "v": cache["v"].at[:, slot].set(v[:, take].astype(cache["v"].dtype)),
+        "k": cache["k"].at[:, slot].set(
+            _to_width(k[:, take], W).astype(cache["k"].dtype)),
+        "v": cache["v"].at[:, slot].set(
+            _to_width(v[:, take], W).astype(cache["v"].dtype)),
         "kv_pos": jnp.zeros((B, L), jnp.int32).at[:, slot].set(kv_pos),
     }
     return attn_out(p, o), cache
@@ -162,31 +189,7 @@ def _self_attention_decode(p, x, cache, cfg, ctx):
     q, k, v = qkv_proj(p, x, cfg)
     q = apply_rope(q, qpos, cfg.rope_theta)
     k = apply_rope(k, qpos, cfg.rope_theta)
-    # match the cache's kv-head sharding before the where-blend write
-    # (identity outside a serving sharding ctx)
-    k = shard_hint(k, "attn_kv")
-    v = shard_hint(v, "attn_kv")
-    L = cache["k"].shape[1]
-    slot = qpos % L                                           # [B, S]
-    # where-blend instead of scatter: GSPMD partitions a batched scatter
-    # on a sharded cache via an f32-upcast rewrite (observed 10.7 GB of
-    # f32 cache copies on the VLM decode); the select is shard-agnostic.
-    # Span positions occupy distinct slots (S <= L), so the one-hot
-    # blend over S is exact: each cache line receives at most one write.
-    hit = (jnp.arange(L)[None, None, :] == slot[:, :, None])  # [B, S, L]
-    feed = ctx.get("feed_mask")
-    if feed is not None:
-        hit &= feed[:, :, None]
-    any_hit = hit.any(axis=1)                                 # [B, L]
-    hsel = hit.astype(cache["k"].dtype)
-    kc_new = jnp.einsum("bsl,bskd->blkd", hsel,
-                        k.astype(cache["k"].dtype))
-    vc_new = jnp.einsum("bsl,bskd->blkd", hsel,
-                        v.astype(cache["v"].dtype))
-    kc = jnp.where(any_hit[:, :, None, None], kc_new, cache["k"])
-    vc = jnp.where(any_hit[:, :, None, None], vc_new, cache["v"])
-    pos_new = jnp.einsum("bsl,bs->bl", hit.astype(jnp.int32), qpos)
-    kv_pos = jnp.where(any_hit, pos_new, cache["kv_pos"])
+    kc, vc, kv_pos, cache = _write_rows(cache, k, v, qpos, ctx)
 
     # mask from absolute positions (per span query)
     w = _window_of(cfg, ctx)
@@ -194,9 +197,57 @@ def _self_attention_decode(p, x, cache, cfg, ctx):
         (kv_pos[:, None, :] <= qpos[:, :, None])              # [B, S, L]
     if w:
         valid &= kv_pos[:, None, :] > (qpos[:, :, None] - w)
-    # the paged path's attention math (bit-exact twins)
-    o = attend(q, kc, vc, valid).astype(x.dtype)
-    return attn_out(p, o), {"k": kc, "v": vc, "kv_pos": kv_pos}
+    # the paged path's attention math (bit-exact twins), on the lines'
+    # first Dh lanes
+    Dh = q.shape[-1]
+    o = attend(q, kc[..., :Dh], vc[..., :Dh], valid).astype(x.dtype)
+    return attn_out(p, o), cache
+
+
+def _write_rows(stack, k, v, qpos, ctx):
+    """In-place write into the stacked caches the decode trunk carries
+    ({"k","v": [n, B, L, K, W], "kv_pos": [n, B, L]}): span position s
+    of slot b lands at (layer, b, qpos[b, s] % L) by one scatter, and
+    feed_mask-gated positions drop (an out-of-range line, as the paged
+    path does). -> (layer's k and v on their first Dh lanes and kv_pos,
+    after the write; the stacks). One dynamic slice takes the layer and
+    the lanes, which the attention fusions then read in place; slicing
+    the lanes off a taken layer would copy the layer first. Under a
+    serving mesh the scatter stays shard-local: its indices run over the
+    layer, slot and line dims and a kv-head-sharded cache splits only
+    the rows it writes (docs/sharding.md)."""
+    i = ctx["layer"]
+    B, S = qpos.shape
+    L, W = stack["k"].shape[2], stack["k"].shape[-1]
+    slot = qpos % L
+    feed = ctx.get("feed_mask")
+    if feed is not None:
+        slot = jnp.where(feed, slot, L)                 # OOB -> dropped
+    b = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S))
+    stack = {
+        "k": stack["k"].at[i, b, slot].set(
+            _to_width(k, W).astype(stack["k"].dtype), mode="drop"),
+        "v": stack["v"].at[i, b, slot].set(
+            _to_width(v, W).astype(stack["v"].dtype), mode="drop"),
+        "kv_pos": stack["kv_pos"].at[i, b, slot].set(qpos, mode="drop"),
+    }
+    start = (i,) + (0,) * (stack["k"].ndim - 1)
+    size = (1,) + stack["k"].shape[1:-1] + (k.shape[-1],)
+    return (jax.lax.dynamic_slice(stack["k"], start, size)[0],
+            jax.lax.dynamic_slice(stack["v"], start, size)[0],
+            stack["kv_pos"][i], stack)
+
+
+def _layer_of(cache, ctx):
+    """Layer ctx["layer"]'s slice of a stacked cache the decode trunk
+    carries."""
+    return jax.tree.map(lambda a: a[ctx["layer"]], cache)
+
+
+def _put_layer(stack, new, ctx):
+    """Write a layer's new (small, recurrent) state back into the
+    carried stack at ctx["layer"]."""
+    return jax.tree.map(lambda a, n: a.at[ctx["layer"]].set(n), stack, new)
 
 
 # ============================ layer kinds ==================================
@@ -342,9 +393,10 @@ def cross_prefill(p, x, cfg, ctx):
 
 def cross_decode(p, x, cache, cfg, ctx):
     g = jnp.tanh(p["gate"].astype(jnp.float32)).astype(x.dtype)
+    mem = _layer_of(cache, ctx)
     x = x + g * _cross_attention(p["attn"],
                                  rms_norm(x, p["ln1"], cfg.norm_eps),
-                                 cache["k"], cache["v"], cfg)
+                                 mem["k"], mem["v"], cfg)
     x = x + ffn(p["ffn"], rms_norm(x, p["ln2"], cfg.norm_eps))
     return x, cache
 
@@ -376,11 +428,11 @@ def rec_prefill(p, x, cfg, ctx):
 
 
 def rec_decode(p, x, cache, cfg, ctx):
-    o, cache = rglru_decode(p["rec"], rms_norm(x, p["ln1"], cfg.norm_eps),
-                            cache, cfg)
+    o, new = rglru_decode(p["rec"], rms_norm(x, p["ln1"], cfg.norm_eps),
+                          _layer_of(cache, ctx), cfg)
     x = x + o
     x = x + ffn(p["ffn"], rms_norm(x, p["ln2"], cfg.norm_eps))
-    return x, cache
+    return x, _put_layer(cache, new, ctx)
 
 
 # ---- "ssm": Mamba2 block (no separate FFN; norm + SSD + residual) ----
@@ -405,9 +457,9 @@ def ssm_layer_prefill(p, x, cfg, ctx):
 
 
 def ssm_layer_decode(p, x, cache, cfg, ctx):
-    o, cache = ssm_decode(p["ssm"], rms_norm(x, p["ln1"], cfg.norm_eps),
-                          cache, cfg)
-    return x + o, cache
+    o, new = ssm_decode(p["ssm"], rms_norm(x, p["ln1"], cfg.norm_eps),
+                        _layer_of(cache, ctx), cfg)
+    return x + o, _put_layer(cache, new, ctx)
 
 
 # ---- "enc": non-causal encoder layer (Whisper encoder) ----
@@ -471,8 +523,9 @@ def dec_decode(p, x, cache, cfg, ctx):
         p["attn"], rms_norm(x, p["ln1"], cfg.norm_eps), cache["self"],
         cfg, ctx)
     x = x + o
+    mem = _layer_of(cache["cross"], ctx)
     x = x + _cross_attention(p["xattn"], rms_norm(x, p["lnx"], cfg.norm_eps),
-                             cache["cross"]["k"], cache["cross"]["v"], cfg)
+                             mem["k"], mem["v"], cfg)
     x = x + ffn(p["ffn"], rms_norm(x, p["ln2"], cfg.norm_eps))
     return x, {"self": self_cache, "cross": cache["cross"]}
 

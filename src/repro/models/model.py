@@ -241,7 +241,13 @@ class Model:
     # ------------------------------ decode -------------------------------
     def _decode_trunk(self, params, caches, tokens, ctx):
         """Shared decode body: embed [B,S] tokens, run every layer group's
-        KIND_DECODE under lax.scan against the caches -> ([B,S,V], caches)."""
+        KIND_DECODE under lax.scan against the caches -> ([B,S,V], caches).
+
+        Dense caches ride in the scan's carry whole: layer i writes only
+        its new rows into the stacked cache (ctx["layer"] = i;
+        `layers._self_attention_decode`), so a donated cache is updated
+        in place and only attention reads it. Paged pools ride as the
+        scan's xs/ys, each layer's pool written by its own scatter."""
         cfg = self.cfg
         x = embed_tokens(params["embed_block"], tokens)
         # pin the residual stream's sharding before the layer scan (same
@@ -250,18 +256,26 @@ class Model:
         # exactly once, instead of leaving GSPMD to re-decide inside the
         # scanned layer body (docs/sharding.md)
         x = shard_hint(x, "act_bsd")
+        paged = "page_table" in ctx
         new_caches = []
         for (pat, count), gp, gc in zip(layer_groups(cfg), params["groups"],
                                         caches):
-            def body(x, sl, pat=pat):
-                pslices, cslices = sl
+            def run(x, pslices, cslices, lctx, pat=pat):
                 ncs = []
                 for j, kind in enumerate(pat):
                     x, nc = KIND_DECODE[kind](pslices[j], x, cslices[j],
-                                              cfg, ctx)
+                                              cfg, lctx)
                     ncs.append(nc)
                 return x, tuple(ncs)
-            x, ngc = jax.lax.scan(body, x, (gp, gc))
+            if paged:
+                x, ngc = jax.lax.scan(
+                    lambda x, sl, run=run: run(x, *sl, ctx), x, (gp, gc))
+            else:
+                def body(carry, sl, run=run):
+                    (x, cs), (pslices, i) = carry, sl
+                    return run(x, pslices, cs, dict(ctx, layer=i)), None
+                (x, ngc), _ = jax.lax.scan(
+                    body, (x, gc), (gp, jnp.arange(count, dtype=jnp.int32)))
             new_caches.append(ngc)
         return lm_logits(params["embed_block"], x, cfg), new_caches
 

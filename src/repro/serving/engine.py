@@ -74,6 +74,15 @@ from repro.spec.scheduler import SPAN_BUCKETS, SlotPhase, SpecConfig
 # callers (tests poking _select_tokens directly) pay nothing
 _OBS_OFF = Telemetry(enabled=False)
 
+
+def insert(full, one, b):
+    """Write a prefilled request's caches (batch 1) into slot b of the
+    dense caches (donated by the engine: updated in place)."""
+    return jax.tree.map(
+        lambda f, o: jax.lax.dynamic_update_slice_in_dim(
+            f, o.astype(f.dtype), b, axis=1), full, one)
+
+
 # span widths the paged feed path jits against (chunked prefill drains
 # prompt backlog through these; decode-only steps ride the width-1 bucket
 # at exactly the dense engine's per-step cost)
@@ -318,7 +327,11 @@ class Engine:
         self._prefill = self._shard_jit(
             lambda p, b, tl: model.prefill(p, b, cache_len=max_len,
                                            true_len=tl))
-        self._decode = self._shard_jit(model.decode_step)
+        # the dense decode writes one row per slot and layer into the
+        # caches it is given (models/model.py::_decode_trunk); every
+        # program that updates the dense caches takes them donated, so
+        # the update aliases its input
+        self._decode = self._shard_jit(model.decode_step, donate_argnums=1)
         # one concatenated device store for all grammars: a request's rows
         # index its grammar's block via the per-grammar row offset (shared
         # by the batched and sequential paths — the store lives on device
@@ -377,11 +390,11 @@ class Engine:
         self.bundles[name] = bundle
         self._rebuild_store_cat()
 
-    def _shard_jit(self, fn):
+    def _shard_jit(self, fn, **jit_kw):
         """jit, plus (when a mesh is configured) the serving
         `use_sharding` context around every call — shard_hint rules
         bind at trace time, and per-bucket retraces re-enter them."""
-        jf = jax.jit(fn)
+        jf = jax.jit(fn, **jit_kw)
         if self.mesh is None:
             return jf
 
@@ -454,11 +467,6 @@ class Engine:
             ok = jnp.any(masked > NEG_INF / 2, axis=-1)
             return masked, ids, ok
 
-        def insert(full, one, b):
-            return jax.tree.map(
-                lambda f, o: jax.lax.dynamic_update_slice_in_dim(
-                    f, o.astype(f.dtype), b, axis=1), full, one)
-
         def span_mask_select(logits, store, rows, cd, eos, constrained,
                              greedy, temp, top_k, top_p, keys):
             """Fused speculation pass: grammar-mask a [B, S, V] span and
@@ -499,11 +507,11 @@ class Engine:
                                     # previous step's resolve
         self._resample = self._shard_jit(resample)
         self._sample_plain = self._shard_jit(select_batch)
-        self._insert_caches = self._shard_jit(insert)
+        self._insert_caches = self._shard_jit(insert, donate_argnums=0)
         self._span_mask_select = self._shard_jit(span_mask_select)
         self._span_decode = self._shard_jit(
             lambda p, c, toks, pos, fm: self.model.decode_span(
-                p, c, toks, pos, feed_mask=fm))
+                p, c, toks, pos, feed_mask=fm), donate_argnums=1)
         self._span_decode_paged = self._shard_jit(
             lambda p, c, toks, pos, fm, pt: self.model.decode_span(
                 p, c, toks, pos, feed_mask=fm,
